@@ -30,12 +30,13 @@ import time
 import jax
 import jax.numpy as jnp
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import distributed as dist
 from repro.core import regularizers as regs
 from repro.core.losses import DecorrConfig, ssl_loss
 from repro.launch.hlo_cost import analyze_hlo
+from repro.launch.mesh import make_mesh
 from repro.train.ssl import (SSLModelConfig, init_ssl_params,
                              make_sharded_ssl_train_step, shard_ssl_batch)
 from repro.optim import adamw, warmup_cosine
@@ -45,7 +46,7 @@ out = {}
 # ---- regularizer-level collective traffic (n, d) = (256, 2048) ----------
 n, d = 256, 2048
 out["reg"] = {"n": n, "d": d}
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 z1 = jax.random.normal(jax.random.PRNGKey(0), (n, d))
 z2 = jax.random.normal(jax.random.PRNGKey(1), (n, d))
 
@@ -67,7 +68,7 @@ naive = shard_map(lambda a, b: regs.r_sum(
 out["reg"]["naive_global_coll_bytes"] = analyze_hlo(
     jax.jit(naive).lower(z1, z2).compile().as_text()).total_collective_bytes
 
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 tp = shard_map(lambda a, b: dist.r_sum_tp(a, b, model_axis="model", batch_axis="data",
                                           q=2, scale=a.shape[0])[None],
                mesh=mesh2, in_specs=(P("data", "model"), P("data", "model")), out_specs=P())
@@ -85,7 +86,7 @@ batch = {"view1": jax.random.normal(jax.random.PRNGKey(2), (n_ssl, 64)),
 rng = jax.random.PRNGKey(4)
 
 for mode in ("local", "global", "tp"):
-    m = jax.make_mesh((8,), ("data",)) if mode != "tp" else jax.make_mesh((2, 4), ("data", "model"))
+    m = make_mesh((8,), ("data",)) if mode != "tp" else make_mesh((2, 4), ("data", "model"))
     cfg = DecorrConfig(style="bt", reg="sum", q=2, block_size=128, distributed=mode)
     step, lag = make_sharded_ssl_train_step(model, cfg, adamw(), warmup_cosine(1e-3, 2, 10), m)
     sb = shard_ssl_batch(batch, m)
@@ -121,15 +122,15 @@ print(json.dumps(out))
 
 
 def run():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # the child counts HLO on virtual CPU devices: it must never claim a chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(REPO, "src"))
     env.pop("XLA_FLAGS", None)
     code = textwrap.dedent(_BODY)
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, env=env, timeout=560
     )
     if proc.returncode != 0:
-        return [fmt_row("distributed/ERROR", 0.0, proc.stderr.strip()[-200:].replace(",", ";"))]
+        raise RuntimeError(f"distributed child failed:\n{proc.stderr[-3000:]}")
     res = json.loads(proc.stdout.strip().splitlines()[-1])
     with open(os.path.join(os.getcwd(), "BENCH_distributed.json"), "w") as f:
         json.dump(res, f, indent=2, sort_keys=True)

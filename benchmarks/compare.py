@@ -97,6 +97,8 @@ HARD_GATES = {
          "the kill-one-replica leg actually stranded and requeued requests"),
         ("fabric.gate.scaling_ok", lambda v: bool(v),
          "N-replica aggregate tok/s meets the hardware-aware scaling target"),
+        ("fabric.gate.tp", lambda v: v >= 2,
+         "the tp oracle sharded the forward over at least two devices"),
         ("fabric.gate.tp_rel_err", lambda v: v < 1e-5,
          "feature-sharded tp forward matches the single-device oracle"),
         ("fabric.gate.embed_max_abs_err", lambda v: v < 1e-5,

@@ -9,10 +9,10 @@ import os
 from typing import Dict, List
 
 from benchmarks.common import fmt_row
+from repro.launch.hlo_cost import PEAKS, TARGET_DEVICE_KIND
 
 RESULTS_DIR = os.environ.get("DRYRUN_RESULTS", "dryrun_results")
 
-V5E_HBM = 16e9
 
 
 def _mesh_name(rec: Dict) -> str:
@@ -61,7 +61,7 @@ def markdown_table(recs: List[Dict], with_guidance: bool = True) -> str:
             lines.append(f"| {r['arch']} | {r['shape']} | {_mesh_name(r)} | ERROR | | | | | | |" + (" |" if with_guidance else ""))
             continue
         rl = r["roofline"]
-        fits = (r["memory"]["temp_bytes"] + r["memory"]["argument_bytes"]) < V5E_HBM
+        fits = (r["memory"]["temp_bytes"] + r["memory"]["argument_bytes"]) < PEAKS[TARGET_DEVICE_KIND]["hbm_bytes"]
         row = (
             f"| {r['arch']} | {r['shape']} | {_mesh_name(r)} "
             f"| {rl['compute_s']:.3g} | {rl['memory_s']:.3g} | {rl['collective_s']:.3g} "

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 SUITES = {
     "complexity": "benchmarks.bench_complexity",       # Fig. 2 / Table 7
@@ -24,22 +25,33 @@ SUITES = {
 }
 
 
-def main() -> None:
+def main() -> int:
+    """Run the named suites (all by default); 1 if any of them raised."""
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = sys.argv[1:] or list(SUITES)
+    failed = []
     print("name,us_per_call,derived")
     for key in wanted:
         mod = importlib.import_module(SUITES[key])
         t0 = time.time()
         try:
             rows = mod.run()
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # run the other suites, then fail the harness
+            traceback.print_exc()
+            failed.append(key)
             rows = [f"{key}/ERROR,0,{type(e).__name__}: {e}"]
         for row in rows:
             print(row, flush=True)
         print(f"# suite {key} took {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        print(f"# FAILED suites: {','.join(failed)}", file=sys.stderr, flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

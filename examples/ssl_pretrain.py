@@ -18,6 +18,7 @@ seconds-scale run.
 
 import argparse
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 from repro.core.losses import DecorrConfig, normalized_bt_regularizer
 from repro.data import SSLDataConfig, ssl_batch
 from repro.decorr import warmup_tune_cache
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for_devices
 from repro.launch.obs_args import (
     add_obs_args,
@@ -44,7 +46,7 @@ from repro.train.ssl import (
 )
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--steps", type=int, default=300)
@@ -72,8 +74,13 @@ def main():
         help="warm the repro.tune cache for the shard-local decorr shapes "
         "before the first step is traced",
     )
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args: argparse.Namespace) -> SimpleNamespace:
+    """Everything ``main`` trains with: model, data, loss config, optimizer,
+    schedule, initial state, the un-jitted train step and the batch
+    function (``mesh`` is None for the single-device step)."""
     if args.tiny:
         model = SSLModelConfig(input_dim=256, backbone_widths=(128,), projector_widths=(256, 256))
         data = SSLDataConfig(input_dim=256, batch=128)
@@ -127,12 +134,24 @@ def main():
         ))
         print(f"[ssl_pretrain] pre-tuned {n_jobs} kernel shapes "
               f"({args.pretune}, {time.time()-t_tune:.1f}s)")
-    step_fn = jax.jit(step_fn)
 
     def batch_fn(step):
         v1, v2 = ssl_batch(data, step)
         b = {"view1": jnp.asarray(v1), "view2": jnp.asarray(v2)}
         return shard_ssl_batch(b, mesh) if mesh is not None else b
+
+    return SimpleNamespace(
+        model=model, data=data, loss_cfg=loss_cfg, opt=opt, sched=sched,
+        state=state, step_fn=step_fn, batch_fn=batch_fn, mesh=mesh,
+    )
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    run = build(args)
+    state, batch_fn = run.state, run.batch_fn
+    step_fn = jax.jit(run.step_fn)
 
     t0 = time.time()
 
@@ -165,7 +184,7 @@ def main():
     )
     finish_train_obs(args, obs)
 
-    v1, v2 = ssl_batch(data, 10_000)
+    v1, v2 = ssl_batch(run.data, 10_000)
     q16 = normalized_bt_regularizer(
         embed(state.params, jnp.asarray(v1)), embed(state.params, jnp.asarray(v2))
     )

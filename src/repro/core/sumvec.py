@@ -27,6 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
@@ -82,6 +83,32 @@ def sumvec_from_matrix(c: Array) -> Array:
 # ---------------------------------------------------------------------------
 
 
+@jax.custom_vjp
+def rfft(x: Array) -> Array:
+    """``jnp.fft.rfft`` along the last axis, differentiable inside ``shard_map``.
+
+    JAX's own rfft transpose re-traces a naive rfft on a dummy primal that
+    drops the varying-manual-axes type, so its VJP fails under ``shard_map``
+    for any shard-varying input.  The transpose is written out instead: with
+    ``A[f, t] = exp(-2 pi i f t / d)``, ``x_bar = Re(A^T t_bar)``, which is
+    ``d * irfft(conj(t_bar) * m)`` where ``m`` halves the bins that the full
+    spectrum holds twice (all but DC and, for even d, Nyquist).
+    """
+    return jnp.fft.rfft(x, axis=-1)
+
+
+def _rfft_fwd(x):
+    return rfft(x), x.shape[-1]
+
+
+def _rfft_bwd(d, t):
+    m = 1.0 / rfft_parseval_weights(d)
+    return (d * jnp.fft.irfft(jnp.conj(t) * m, n=d, axis=-1),)
+
+
+rfft.defvjp(_rfft_fwd, _rfft_bwd)
+
+
 def frequency_accumulator(z1: Array, z2: Array, *, precision_dtype=jnp.float32) -> Array:
     """``G = sum_k conj(F(z1_k)) o F(z2_k)`` — rfft bins, complex64.
 
@@ -92,8 +119,8 @@ def frequency_accumulator(z1: Array, z2: Array, *, precision_dtype=jnp.float32) 
     """
     z1 = z1.astype(precision_dtype)
     z2 = z2.astype(precision_dtype)
-    f1 = jnp.fft.rfft(z1, axis=-1)
-    f2 = jnp.fft.rfft(z2, axis=-1)
+    f1 = rfft(z1)
+    f2 = rfft(z2)
     return jnp.sum(jnp.conj(f1) * f2, axis=0)
 
 
@@ -164,8 +191,8 @@ def grouped_frequency_accumulator(
     """
     b1 = blockify(z1.astype(precision_dtype), block_size)
     b2 = blockify(z2.astype(precision_dtype), block_size)
-    f1 = jnp.fft.rfft(b1, axis=-1)  # (n, nb, nf)
-    f2 = jnp.fft.rfft(b2, axis=-1)
+    f1 = rfft(b1)  # (n, nb, nf)
+    f2 = rfft(b2)
     return jnp.einsum("kif,kjf->ijf", jnp.conj(f1), f2)
 
 
@@ -196,13 +223,16 @@ def grouped_sumvec_from_matrix(c: Array, block_size: int) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def rfft_parseval_weights(d: int) -> jax.Array:
-    """w_f such that sum_t s[t]^2 = (1/d) sum_f w_f |S_rfft[f]|^2."""
-    nf = d // 2 + 1
-    w = jnp.full((nf,), 2.0, dtype=jnp.float32)
-    w = w.at[0].set(1.0)
+def rfft_parseval_weights(d: int) -> np.ndarray:
+    """w_f such that sum_t s[t]^2 = (1/d) sum_f w_f |S_rfft[f]|^2.
+
+    A NumPy constant: it carries no sharding, so it mixes with arrays on any
+    mesh (Explicit axes included) without a device assignment of its own.
+    """
+    w = np.full((d // 2 + 1,), 2.0, dtype=np.float32)
+    w[0] = 1.0
     if d % 2 == 0:
-        w = w.at[-1].set(1.0)
+        w[-1] = 1.0
     return w
 
 

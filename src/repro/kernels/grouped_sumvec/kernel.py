@@ -27,7 +27,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.pallas_utils import INTERPRET, LANE, SUBLANE, next_multiple, pad_axis
+from repro.kernels.pallas_utils import (
+    LANE,
+    SUBLANE,
+    cotangent_like,
+    dot_f32,
+    next_multiple,
+    pad_axis,
+    pallas_call,
+)
 from repro.tune.dispatch import best_config
 
 
@@ -43,7 +51,7 @@ def _mm_kernel(a_ref, b_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+    o_ref[...] += dot_f32(a_ref[...], b_ref[...])
 
 
 def _pmatmul_raw(a, b, tm=None, tn=None, tk=None):
@@ -62,8 +70,9 @@ def _pmatmul_raw(a, b, tm=None, tn=None, tk=None):
     a = pad_axis(pad_axis(a, 0, mp), 1, kp)
     b = pad_axis(pad_axis(b, 0, kp), 1, np_)
     grid = (mp // tm, np_ // tn, kp // tk)
-    out = pl.pallas_call(
+    out = pallas_call(
         _mm_kernel,
+        a.astype(jnp.float32), b.astype(jnp.float32),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
@@ -71,8 +80,7 @@ def _pmatmul_raw(a, b, tm=None, tn=None, tk=None):
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=INTERPRET,
-    )(a.astype(jnp.float32), b.astype(jnp.float32))
+    )
     return out[:m, :n]
 
 
@@ -89,7 +97,7 @@ def _pmatmul_bwd(res, g):
     a, b = res
     da = _pmatmul_raw(g, b.T)
     db = _pmatmul_raw(a.T, g)
-    return da.astype(a.dtype), db.astype(b.dtype)
+    return cotangent_like(da.astype(a.dtype), a), cotangent_like(db.astype(b.dtype), b)
 
 
 pmatmul.defvjp(_pmatmul_fwd, _pmatmul_bwd)
@@ -109,7 +117,7 @@ def _fo_kernel(a_ref, b_ref, o_ref):
 
     a = a_ref[0]  # (tk, N)
     b = b_ref[0]  # (tk, tn)
-    o_ref[0] += jnp.dot(a.T, b, preferred_element_type=jnp.float32)
+    o_ref[0] += dot_f32(a.T, b)
 
 
 def _freq_outer_raw(a, b, tk=None, tn=None):
@@ -127,8 +135,9 @@ def _freq_outer_raw(a, b, tk=None, tn=None):
     a = pad_axis(pad_axis(a, 1, kp), 2, npad)
     b = pad_axis(pad_axis(b, 1, kp), 2, npad)
     grid = (f, npad // tn, kp // tk)
-    out = pl.pallas_call(
+    out = pallas_call(
         _fo_kernel,
+        a.astype(jnp.float32), b.astype(jnp.float32),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tk, npad), lambda ff, j, kk: (ff, kk, 0)),
@@ -136,8 +145,7 @@ def _freq_outer_raw(a, b, tk=None, tn=None):
         ],
         out_specs=pl.BlockSpec((1, npad, tn), lambda ff, j, kk: (ff, 0, j)),
         out_shape=jax.ShapeDtypeStruct((f, npad, npad), jnp.float32),
-        interpret=INTERPRET,
-    )(a.astype(jnp.float32), b.astype(jnp.float32))
+    )
     return out[:, :n, :nb]
 
 
@@ -147,7 +155,7 @@ def _freq_outer_raw(a, b, tk=None, tn=None):
 
 
 def _fm_kernel(a_ref, m_ref, o_ref):
-    o_ref[0] = jnp.dot(a_ref[0], m_ref[0], preferred_element_type=jnp.float32)
+    o_ref[0] = dot_f32(a_ref[0], m_ref[0])
 
 
 def _freq_mat_raw(a, m, tk=None):
@@ -163,8 +171,9 @@ def _freq_mat_raw(a, m, tk=None):
     a = pad_axis(pad_axis(a, 1, kp), 2, npad)
     m = pad_axis(pad_axis(m, 1, npad), 2, n2pad)
     grid = (f, kp // tk)
-    out = pl.pallas_call(
+    out = pallas_call(
         _fm_kernel,
+        a.astype(jnp.float32), m.astype(jnp.float32),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tk, npad), lambda ff, kk: (ff, kk, 0)),
@@ -172,8 +181,7 @@ def _freq_mat_raw(a, m, tk=None):
         ],
         out_specs=pl.BlockSpec((1, tk, n2pad), lambda ff, kk: (ff, kk, 0)),
         out_shape=jax.ShapeDtypeStruct((f, kp, n2pad), jnp.float32),
-        interpret=INTERPRET,
-    )(a.astype(jnp.float32), m.astype(jnp.float32))
+    )
     return out[:, :k, :n2]
 
 
@@ -192,7 +200,7 @@ def _fo_bwd(res, g):
     # dA[f] = b[f] @ g[f]^T ; dB[f] = a[f] @ g[f]
     da = _freq_mat_raw(b, jnp.swapaxes(g, 1, 2))
     db = _freq_mat_raw(a, g)
-    return da.astype(a.dtype), db.astype(b.dtype)
+    return cotangent_like(da.astype(a.dtype), a), cotangent_like(db.astype(b.dtype), b)
 
 
 freq_outer.defvjp(_fo_fwd, _fo_bwd)
@@ -212,7 +220,7 @@ def _fm_bwd(res, g):
     a, m = res
     da = _freq_mat_raw(g, jnp.swapaxes(m, 1, 2))
     dm = _freq_outer_raw(a, g)
-    return da.astype(a.dtype), dm.astype(m.dtype)
+    return cotangent_like(da.astype(a.dtype), a), cotangent_like(dm.astype(m.dtype), m)
 
 
 freq_mat.defvjp(_fm_fwd, _fm_bwd)

@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.sumvec import rfft_parseval_weights
 from repro.kernels.grouped_sumvec import kernel as K
 from repro.kernels.pallas_utils import dft_matrices, irfft_basis
 from repro.tune import space as tune_space
@@ -83,14 +84,6 @@ def grouped_frequency_accumulator_kernel(
     return g_r, g_i
 
 
-def _parseval_weights(b: int) -> Array:
-    nf = b // 2 + 1
-    w = jnp.full((nf,), 2.0, jnp.float32).at[0].set(1.0)
-    if b % 2 == 0:
-        w = w.at[-1].set(1.0)
-    return w
-
-
 @functools.partial(jax.jit, static_argnames=("block_size", "q", "scale"))
 def r_sum_kernel(
     z1: Array,
@@ -109,7 +102,7 @@ def r_sum_kernel(
     g_r = g_r / s
     g_i = g_i / s
     nf, nb, _ = g_r.shape
-    w = _parseval_weights(b)[:, None, None]
+    w = rfft_parseval_weights(b)[:, None, None]
     eye = jnp.eye(nb, dtype=jnp.float32)
     if q == 2:
         sq = jnp.sum(w * (g_r**2 + g_i**2), axis=0) / b  # (nb, nb)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_utils import INTERPRET, LANE, SUBLANE, next_multiple, pad_axis
+from repro.kernels.pallas_utils import LANE, SUBLANE, next_multiple, pad_axis, pallas_call
 
 NEG_INF = -1e30
 
@@ -137,7 +137,7 @@ def paged_decode_kernel_call(q, k_pages, v_pages, block_tables, lens, *, scale, 
             pltpu.VMEM((kvp, rp, LANE), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(
             _paged_decode_kernel,
             page=page,
@@ -145,8 +145,8 @@ def paged_decode_kernel_call(q, k_pages, v_pages, block_tables, lens, *, scale, 
             softcap=float(softcap or 0.0),
             window=int(window or 0),
         ),
+        block_tables.astype(jnp.int32), lens.astype(jnp.int32), q, k_pages, v_pages,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvp, rp, hdp), jnp.float32),
-        interpret=INTERPRET,
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), q, k_pages, v_pages)
+    )
     return out[:, :kv, :n_rep, :hd].reshape(b, h, hd)

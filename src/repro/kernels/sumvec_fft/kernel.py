@@ -27,7 +27,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.pallas_utils import INTERPRET, LANE, SUBLANE, next_multiple, pad_axis
+from repro.kernels.pallas_utils import (
+    LANE,
+    SUBLANE,
+    cotangent_like,
+    dot_f32,
+    next_multiple,
+    pad_axis,
+    pallas_call,
+)
 from repro.tune.dispatch import best_config
 
 
@@ -46,9 +54,8 @@ def _cmm_kernel(ar_ref, ai_ref, br_ref, bi_ref, cr_ref, ci_ref):
 
     ar, ai = ar_ref[...], ai_ref[...]
     br, bi = br_ref[...], bi_ref[...]
-    dot = lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32)
-    cr_ref[...] += dot(ar, br) - dot(ai, bi)
-    ci_ref[...] += dot(ar, bi) + dot(ai, br)
+    cr_ref[...] += dot_f32(ar, br) - dot_f32(ai, bi)
+    ci_ref[...] += dot_f32(ar, bi) + dot_f32(ai, br)
 
 
 def _cmatmul_raw(ar, ai, br, bi, tm=None, tn=None, tk=None):
@@ -70,21 +77,13 @@ def _cmatmul_raw(ar, ai, br, bi, tm=None, tn=None, tk=None):
     a_spec = pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk))
     b_spec = pl.BlockSpec((tk, tn), lambda i, j, kk: (kk, j))
     o_spec = pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j))
-    cr, ci = pl.pallas_call(
+    cr, ci = pallas_call(
         _cmm_kernel,
+        ar.astype(jnp.float32), ai.astype(jnp.float32), br.astype(jnp.float32), bi.astype(jnp.float32),
         grid=grid,
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[o_spec, o_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        ],
-        interpret=INTERPRET,
-    )(
-        ar.astype(jnp.float32),
-        ai.astype(jnp.float32),
-        br.astype(jnp.float32),
-        bi.astype(jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((mp, np_), jnp.float32)] * 2,
     )
     return cr[:m, :n], ci[:m, :n]
 
@@ -105,7 +104,7 @@ def _cmm_bwd(res, g):
     # dA = g @ B^H ;  dB = A^H @ g   (conjugate transposes)
     dar, dai = _cmatmul_raw(gr, gi, br.T, -bi.T)
     dbr, dbi = _cmatmul_raw(ar.T, -ai.T, gr, gi)
-    return dar, dai, dbr, dbi
+    return tuple(cotangent_like(c, x) for c, x in zip((dar, dai, dbr, dbi), res))
 
 
 cmatmul.defvjp(_cmm_fwd, _cmm_bwd)
@@ -141,8 +140,9 @@ def _ctwiddle_raw(xr, xi, wr, wi, tn=None):
     wr2 = pad_axis(wr, 0, dp).reshape(1, dp)
     wi2 = pad_axis(wi, 0, dp).reshape(1, dp)
     grid = (np_ // tn,)
-    yr, yi = pl.pallas_call(
+    yr, yi = pallas_call(
         _ctw_kernel,
+        xr.astype(jnp.float32), xi.astype(jnp.float32), wr2, wi2,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tn, dp), lambda i: (i, 0)),
@@ -154,12 +154,8 @@ def _ctwiddle_raw(xr, xi, wr, wi, tn=None):
             pl.BlockSpec((tn, dp), lambda i: (i, 0)),
             pl.BlockSpec((tn, dp), lambda i: (i, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_, dp), jnp.float32),
-            jax.ShapeDtypeStruct((np_, dp), jnp.float32),
-        ],
-        interpret=INTERPRET,
-    )(xr.astype(jnp.float32), xi.astype(jnp.float32), wr2, wi2)
+        out_shape=[jax.ShapeDtypeStruct((np_, dp), jnp.float32)] * 2,
+    )
     return yr[:n, :d], yi[:n, :d]
 
 
@@ -181,7 +177,7 @@ def _ctw_bwd(res, g):
     # dw = sum_k conj(x_k) o g_k   (w is a constant basis; grads rarely used)
     dwr = jnp.sum(xr * gr + xi * gi, axis=0)
     dwi = jnp.sum(xr * gi - xi * gr, axis=0)
-    return dxr, dxi, dwr, dwi
+    return tuple(cotangent_like(c, x) for c, x in zip((dxr, dxi, dwr, dwi), res))
 
 
 ctwiddle.defvjp(_ctw_fwd, _ctw_bwd)

@@ -334,18 +334,25 @@ def analyze_hlo(hlo: str) -> HLOAnalysis:
 
 
 # ---------------------------------------------------------------------------
-# Roofline terms (TPU v5e constants from the assignment)
+# Roofline terms
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (50 GB/s per link).
+# A device that is not here has no roofline: lookups raise KeyError.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9, "hbm_bytes": 16e9},
+}
+# The chip the analytic tiers (tune cost model, production dry-run) model.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
-def roofline_terms(analysis: HLOAnalysis) -> Dict[str, float]:
-    t_compute = analysis.flops / PEAK_FLOPS
-    t_memory = analysis.hbm_bytes / HBM_BW
-    t_coll = analysis.total_collective_bytes / ICI_BW
+def roofline_terms(analysis: HLOAnalysis, device_kind: str = TARGET_DEVICE_KIND) -> Dict[str, float]:
+    peak = PEAKS[device_kind]
+    t_compute = analysis.flops / peak["flops"]
+    t_memory = analysis.hbm_bytes / peak["hbm_bw"]
+    t_coll = analysis.total_collective_bytes / peak["ici_bw"]
     dominant = max(
         ("compute", t_compute), ("memory", t_memory), ("collective", t_coll),
         key=lambda kv: kv[1],

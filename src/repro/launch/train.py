@@ -21,6 +21,7 @@ from repro.configs import get_config
 from repro.core.decorrelation import LMDecorrConfig
 from repro.core.losses import DecorrConfig
 from repro.data import LMDataConfig, lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.obs_args import (
     add_obs_args,
     attach_train_step,
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -31,7 +31,7 @@ from repro.models.common import (
     rms_norm,
     softcap,
 )
-from repro.parallel.sharding import shard
+from repro.parallel.sharding import lookup, shard
 
 Array = jax.Array
 
@@ -228,7 +228,7 @@ def _embed_inputs(params, cfg: ArchConfig, tokens, embeds):
         emb = params["embed"].astype(cfg.compute_dtype)
         x = sum(emb[q][tokens[..., q]] for q in range(cfg.n_codebooks))
     else:
-        x = params["embed"].astype(cfg.compute_dtype)[tokens]
+        x = lookup(params["embed"].astype(cfg.compute_dtype), tokens, ("batch", "seq", "embed"))
     if cfg.scale_embed:
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), cfg.compute_dtype)
     return shard(x, ("batch", "seq", "embed"))

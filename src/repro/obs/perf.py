@@ -20,7 +20,10 @@ executable hid behind the others.  ``ExecTimer`` is the attribution layer:
     ``min(1, analytic_bound_s / best_measured_s)`` and the analytic-vs-
     measured disagreement ratio ``best_measured_s / analytic_bound_s`` —
     directly feeding the ROADMAP debt "analytic tier favors large pages —
-    validate against wall time".
+    validate against wall time".  The join needs the measuring device's
+    peaks (``launch.hlo_cost.PEAKS``, keyed by ``device_kind``): on a device
+    that is not in that table nothing is attached and no roofline gauge is
+    published, so a host-CPU timing is never divided by a chip's peaks.
 
 Everything is lazy and failure-tolerant: the HLO analyzer import happens
 only when something attaches (the analytic tier never pays it), a backend
@@ -63,6 +66,7 @@ class ExecTimer:
         clock=time.perf_counter,
     ):
         self.enabled = bool(enabled)
+        self._device_kind: Optional[str] = None  # read at the first roofline join
         self.registry = registry if registry is not None else MetricsRegistry()
         self._clock = clock
         self._lock = threading.Lock()
@@ -125,6 +129,16 @@ class ExecTimer:
 
     # -- the analytic join ----------------------------------------------------
 
+    def _peaks(self) -> Optional[Dict[str, float]]:
+        """The measuring device's peaks, or None when it has no table entry."""
+        from repro.launch.hlo_cost import PEAKS
+
+        if self._device_kind is None:
+            import jax
+
+            self._device_kind = jax.devices()[0].device_kind
+        return PEAKS.get(self._device_kind)
+
     def record_compile(self, name: str, seconds: float):
         if not self.enabled:
             return
@@ -144,16 +158,20 @@ class ExecTimer:
         compile_s: Optional[float] = None,
     ):
         """Attach analytic costs directly (tests; callers with their own
-        cost model).  ``bound_s`` defaults to the hlo_cost roofline bound."""
+        cost model).  ``bound_s`` defaults to the roofline bound on the
+        measuring device's peaks; a device without peaks attaches nothing."""
         if not self.enabled:
             return
+        if compile_s is not None:
+            self.record_compile(name, compile_s)
+        peak = self._peaks()
+        if peak is None:
+            return
         if bound_s is None:
-            from repro.launch.hlo_cost import HBM_BW, ICI_BW, PEAK_FLOPS
-
             terms = {
-                "compute": flops / PEAK_FLOPS,
-                "memory": hbm_bytes / HBM_BW,
-                "collective": collective_bytes / ICI_BW,
+                "compute": flops / peak["flops"],
+                "memory": hbm_bytes / peak["hbm_bw"],
+                "collective": collective_bytes / peak["ici_bw"],
             }
             dominant = dominant or max(terms, key=terms.get)
             bound_s = max(terms.values())
@@ -165,15 +183,14 @@ class ExecTimer:
                 "bound_s": float(bound_s),
                 "dominant": dominant,
             }
-        if compile_s is not None:
-            self.record_compile(name, compile_s)
 
-    def attach_compiled(self, name: str, compiled, compile_s: Optional[float] = None) -> bool:
+    def attach_compiled(self, name: str, compiled) -> bool:
         """Join one AOT-compiled executable: parse its optimized HLO for
         trip-exact FLOPs/bytes and store the roofline terms.  Idempotent per
-        name; returns False (and attaches nothing) when the backend exposes
-        no HLO text or the analyzer cannot parse it."""
-        if not self.enabled:
+        name; returns False (and attaches nothing) when the device has no
+        peaks, the backend exposes no HLO text or the analyzer cannot parse
+        it."""
+        if not self.enabled or self._peaks() is None:
             return False
         with self._lock:
             if name in self._analysis:
@@ -183,7 +200,7 @@ class ExecTimer:
             from repro.launch.hlo_cost import analyze_hlo, roofline_terms
 
             a = analyze_hlo(hlo)
-            terms = roofline_terms(a)
+            terms = roofline_terms(a, self._device_kind)
         except Exception:
             return False
         self.attach_analysis(
@@ -193,14 +210,14 @@ class ExecTimer:
             collective_bytes=a.total_collective_bytes,
             bound_s=terms["bound_s"],
             dominant=terms["dominant"],
-            compile_s=compile_s,
         )
         return True
 
     def attach_jit(self, name: str, fn, *args, **kw) -> bool:
         """AOT lower+compile a jitted callable purely for attribution (the
         caller keeps executing its own jit cache) and join the result.
-        Records the lower+compile wall time as the compile gauge."""
+        Records the lower+compile wall time as the compile gauge on any
+        device; the roofline join only where ``attach_compiled`` makes it."""
         if not self.enabled:
             return False
         with self._lock:
@@ -211,7 +228,8 @@ class ExecTimer:
             compiled = fn.lower(*args, **kw).compile()
         except Exception:
             return False
-        return self.attach_compiled(name, compiled, compile_s=self._clock() - t0)
+        self.record_compile(name, self._clock() - t0)
+        return self.attach_compiled(name, compiled)
 
     @property
     def analyzed(self) -> int:

@@ -26,7 +26,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 _STATE = threading.local()
 
@@ -55,6 +55,10 @@ def current_rules() -> Dict[str, Optional[Tuple[str, ...]]]:
     return getattr(_STATE, "rules", DEFAULT_RULES)
 
 
+def _has_explicit_axes(mesh: Mesh) -> bool:
+    return any(t == AxisType.Explicit for t in mesh.axis_types)
+
+
 @contextlib.contextmanager
 def sharding_context(mesh: Mesh, rules: Optional[Dict] = None):
     """Install mesh + logical rules for model-internal annotations."""
@@ -65,8 +69,15 @@ def sharding_context(mesh: Mesh, rules: Optional[Dict] = None):
         merged.update(rules)
     _STATE.mesh = mesh
     _STATE.rules = merged
+    # code traced under an Explicit-axis mesh must see the axes its inputs
+    # are sharded over
+    axes_in_scope = (
+        jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+        if _has_explicit_axes(mesh) else contextlib.nullcontext()
+    )
     try:
-        yield
+        with axes_in_scope:
+            yield
     finally:
         _STATE.mesh = prev_mesh
         if prev_rules is None:
@@ -126,7 +137,31 @@ def shard(x, axes: Sequence[Optional[str]]):
         for a in mesh_axes:
             n *= mesh.shape[a]
         fixed.append(part if (dim % n == 0 and dim >= n) else None)
+    if _has_explicit_axes(mesh):  # a constraint only asserts on Explicit axes
+        return jax.sharding.reshard(x, PartitionSpec(*fixed))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PartitionSpec(*fixed)))
+
+
+def on_mesh(tree):
+    """Under an Explicit-axis mesh, replicate leaves that are on no mesh yet
+    (unplaced params), so their gradients have a sharding to land in."""
+    mesh = current_mesh()
+    if mesh is None or not _has_explicit_axes(mesh):
+        return tree
+    return jax.tree.map(
+        lambda x: jax.sharding.reshard(x, PartitionSpec())
+        if jax.typeof(x).sharding.mesh.empty else x,
+        tree,
+    )
+
+
+def lookup(table, idx, axes: Sequence[Optional[str]]):
+    """``table[idx]`` with the result sharded by logical ``axes``; an
+    Explicit-axis mesh cannot infer where a gather by sharded indices lands."""
+    mesh = current_mesh()
+    if mesh is None or not _has_explicit_axes(mesh):
+        return table[idx]
+    return table.at[idx].get(out_sharding=logical_to_spec(axes))
 
 
 def named_sharding(axes: Sequence[Optional[str]]) -> Optional[NamedSharding]:

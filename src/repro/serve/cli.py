@@ -589,6 +589,9 @@ def main(argv=None) -> int:
                    help="alert rules as a JSON file path or inline JSON list "
                         "(default: the built-in serve rules)")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.fabric and not (args.lm_arch and args.continuous):
         p.error("--fabric routes continuous LM replicas; it requires "

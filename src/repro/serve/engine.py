@@ -31,8 +31,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpointer import latest_step, restore_checkpoint
 from repro.serve.buckets import BucketPolicy, bucket_for, bucket_sizes
@@ -84,8 +84,6 @@ class ServeEngine:
                 self._tp_specs = self._make_tp_specs()
                 # place params once (projector output layer feature-sharded,
                 # everything else replicated) so encode never re-shards
-                from jax.sharding import NamedSharding
-
                 self.params = jax.tree_util.tree_map(
                     lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
                     self.params, self._tp_specs,
@@ -241,13 +239,23 @@ class ServeEngine:
             x = jnp.concatenate([x, jnp.zeros((b - n, x.shape[1]), self.dtype)], axis=0)
         fn = self._embed_fn(b)
         if self.perf is None:
-            return fn(self.params, x)[:n]
+            return self._strip(fn(self.params, x), n)
         # attribution path: block so the wall time covers device execution
         # (the default perf=None path stays fully async)
         t0 = self.perf.start()
         z = fn(self.params, x)
         jax.block_until_ready(z)
         self.perf.observe(f"embed_b{b}", self.perf.elapsed(t0))
+        return self._strip(z, n)
+
+    def _strip(self, z: Array, n: int) -> Array:
+        """The first ``n`` rows of a bucket's output. Rows sharded over the
+        mesh are gathered first: an Explicit-axis mesh gives a slice across
+        row shards no sharding."""
+        if n == z.shape[0]:
+            return z
+        if self.mesh is not None:
+            z = jax.device_put(z, NamedSharding(self.mesh, P()))
         return z[:n]
 
 

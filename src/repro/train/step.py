@@ -27,6 +27,7 @@ from repro.core.decorrelation import lm_decorrelation_loss
 from repro.models.common import ArchConfig
 from repro.models.transformer import forward
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
+from repro.parallel.sharding import on_mesh
 from repro.train.train_state import TrainState
 
 Array = jax.Array
@@ -36,7 +37,9 @@ def cross_entropy(logits: Array, labels: Array) -> Array:
     """Mean CE. logits (..., V) f32; labels (...) int32 (extra dims ok)."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    # a masked sum, not a gather: it needs no rule for where the vocab axis lives
+    hit = labels[..., None] == jnp.arange(logits.shape[-1], dtype=labels.dtype)
+    gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
     return jnp.mean(logz - gold)
 
 
@@ -82,6 +85,7 @@ def make_train_step(
         )
 
     def train_step(state: TrainState, batch: Dict[str, Array]) -> Tuple[TrainState, Dict]:
+        state = on_mesh(state)
         rng = jax.random.fold_in(state.rng, state.step)
 
         if num_microbatches <= 1:

@@ -14,13 +14,13 @@ Three fidelity tiers, all deterministic on CPU/interpret:
                           primary key in dry mode).
 
 Analytic ranking is a roofline scalar, not flops-lexicographic:
-``max(flops / PEAK_FLOPS, hbm_bytes / HBM_BW) + grid_steps * step_overhead``
+``max(flops / peak_flops, hbm_bytes / hbm_bw) + grid_steps * step_overhead``
 (flops and vmem as deterministic tiebreaks).  Padding FLOPs on the MXU are
 nearly free while re-reads and per-grid-step dispatch are not — a
 flops-first ordering would pick degenerate minimum-sublane tiles (tm = 8)
 for any m a larger tile would pad, which is exactly backwards on hardware.
-Constants mirror launch.hlo_cost's TPU v5e roofline (kept local: the
-analytic tier must not import repro.launch).
+Peaks are those of ``launch.hlo_cost.TARGET_DEVICE_KIND`` in its one
+``PEAKS`` table.
 """
 
 from __future__ import annotations
@@ -31,13 +31,11 @@ from typing import Callable, Dict, Tuple
 import jax
 
 from repro.kernels.pallas_utils import LANE, SUBLANE, next_multiple
+from repro.launch.hlo_cost import PEAKS, TARGET_DEVICE_KIND
 from repro.tune.space import Config, Shape, vmem_bytes
 
 F32 = 4
-# TPU v5e roofline constants (see launch.hlo_cost; duplicated to keep the
-# analytic dispatch tier free of the launch package)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+_PEAK = PEAKS[TARGET_DEVICE_KIND]
 # charged per grid step: DMA descriptor / pipeline dispatch latency
 GRID_STEP_OVERHEAD_S = 1e-6
 # batch the plan cost model amortizes batch-independent stages over (the
@@ -154,7 +152,7 @@ def rank_key(cost: Dict[str, float], kernel: str = "") -> Tuple[float, float, fl
         # Rank flops-first.
         return (cost["flops"], cost["hbm_bytes"], cost.get("vmem_bytes", 0.0))
     roofline_s = (
-        max(cost["flops"] / PEAK_FLOPS, cost["hbm_bytes"] / HBM_BW)
+        max(cost["flops"] / _PEAK["flops"], cost["hbm_bytes"] / _PEAK["hbm_bw"])
         + cost.get("grid_steps", 0.0) * GRID_STEP_OVERHEAD_S
     )
     return (roofline_s, cost["flops"], cost.get("vmem_bytes", 0.0))

@@ -86,7 +86,7 @@ def _tile_options(dim: int, unit: int, grid) -> List[int]:
 def vmem_bytes(kernel: str, shape: Shape, cfg: Config) -> int:
     if kernel == "xcorr_offdiag":
         tn, td = cfg["tile_n"], cfg["tile_d"]
-        return 2 * (2 * tn * td * F32) + td * td * F32
+        return 2 * (2 * tn * td + SUBLANE * td) * F32 + td * td * F32
     if kernel == "cmatmul":
         tm, tn, tk = cfg["tm"], cfg["tn"], cfg["tk"]
         return 2 * (2 * tm * tk + 2 * tk * tn + 2 * tm * tn) * F32

@@ -228,7 +228,7 @@ def test_vic_global_uses_global_moments():
         _COMMON,
         """
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = jax.make_mesh((8,), ("data",))
         n, d = 64, 12
@@ -242,7 +242,7 @@ def test_vic_global_uses_global_moments():
         def sharded(a, b):
             return ssl_loss(a, b, cfg)[0][None]
 
-        got = float(sharded(z1, z2)[0])
+        got = float(jax.jit(sharded)(z1, z2)[0])
         cfg_l = DecorrConfig(style="vic", reg="sum", q=2, permute=False)
         want = float(ssl_loss(z1, z2, cfg_l)[0])
         # and what the old shard-local-moments bug would have computed
@@ -262,7 +262,7 @@ def test_regularizer_global_ddof_uses_exact_effective_scale():
     res = run_in_subprocess(
         """
         from functools import partial
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import regularizers as regs
         from repro.decorr import engine
@@ -280,7 +280,7 @@ def test_regularizer_global_ddof_uses_exact_effective_scale():
             scale = float(max(a.shape[0] - 1, 1))
             return engine.regularizer(a, a, cfg, scale, ddof=1)[None]
 
-        got = float(sharded(zc)[0])
+        got = float(jax.jit(sharded)(zc)[0])
         want = float(regs.r_sum_auto(zc, zc, q=2, scale=float(n - 1)))
         legacy = float(regs.r_sum_auto(zc, zc, q=2, scale=float((n // 8 - 1) * 8)))
         print(json.dumps({"got": got, "want": want, "legacy": legacy}))

@@ -30,6 +30,18 @@ from repro.obs import (
     sanitize_name,
 )
 
+@pytest.fixture
+def chip_peaks(monkeypatch):
+    """Give the test process's device the v5e peaks, so the roofline join runs
+    on the CPU; a device missing from the table gets no roofline at all."""
+    import jax
+
+    from repro.launch import hlo_cost
+
+    monkeypatch.setitem(
+        hlo_cost.PEAKS, jax.devices()[0].device_kind, hlo_cost.PEAKS["TPU v5 lite"]
+    )
+
 
 # ---------------------------------------------------------------------------
 # Registry primitives
@@ -434,7 +446,7 @@ class TestObsBundle:
         assert p.start() is False and p.stop() is None
         assert p.metrics()["profiler_active"] == 0.0
 
-    def test_perf_and_flight_endpoints(self):
+    def test_perf_and_flight_endpoints(self, chip_peaks):
         obs = Obs()
         obs.perf.attach_analysis("decode", flops=2e9, hbm_bytes=1e8)
         obs.perf.observe("decode", 0.004)
@@ -479,7 +491,7 @@ class TestExecTimer:
         assert "roofline_utilization" not in row  # no analysis attached yet
         assert t.registry.get("exec_seconds").labels(executable="step").count == 3
 
-    def test_analysis_join_derives_roofline_fields(self):
+    def test_analysis_join_derives_roofline_fields(self, chip_peaks):
         t = ExecTimer()
         t.attach_analysis("step", flops=1e9, hbm_bytes=4e6, compile_s=0.5)
         t.observe("step", 1e-3)
@@ -495,7 +507,7 @@ class TestExecTimer:
         assert row["compile_s"] == 0.5
         assert row["dominant"] in ("compute", "memory", "collective")
 
-    def test_utilization_clamps_to_one(self):
+    def test_utilization_clamps_to_one(self, chip_peaks):
         t = ExecTimer()
         # analytic bound far ABOVE the measured time (pessimistic model):
         # the gauge clamps at 1.0 instead of reporting >100% of roofline
@@ -515,7 +527,7 @@ class TestExecTimer:
         rep = t.report(top_k=1)
         assert rep["executables"] == 2 and len(rep["top"]) == 1
 
-    def test_publish_emits_labelled_gauges(self):
+    def test_publish_emits_labelled_gauges(self, chip_peaks):
         reg = MetricsRegistry()
         t = ExecTimer(reg)
         t.attach_analysis("step", flops=1e9, hbm_bytes=1e6)
@@ -545,7 +557,7 @@ class TestExecTimer:
         assert t.snapshot() == [] and t.analyzed == 0
         assert t.metrics()["perf_observed_total"] == 0.0
 
-    def test_attach_jit_parses_real_hlo(self):
+    def test_attach_jit_parses_real_hlo(self, chip_peaks):
         import jax
         import jax.numpy as jnp
 
@@ -561,7 +573,7 @@ class TestExecTimer:
         # idempotent: re-attaching the same name is a no-op that reports True
         assert t.attach_jit("matmul", fn, x, x)
 
-    def test_attach_compiled_tolerates_bad_backends(self):
+    def test_attach_compiled_tolerates_bad_backends(self, chip_peaks):
         class NoText:
             def as_text(self):
                 raise RuntimeError("no HLO here")
@@ -569,6 +581,32 @@ class TestExecTimer:
         t = ExecTimer()
         assert t.attach_compiled("weird", NoText()) is False
         assert t.analyzed == 0
+
+    def test_no_roofline_for_a_device_without_peaks(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.launch.hlo_cost import PEAKS
+
+        assert jax.devices()[0].device_kind not in PEAKS
+        reg = MetricsRegistry()
+        t = ExecTimer(reg)
+        t.attach_analysis("step", flops=1e9, hbm_bytes=1e6, compile_s=0.5)
+        fn = jax.jit(lambda a, b: a @ b)
+        x = jnp.ones((32, 32), jnp.float32)
+        assert t.attach_jit("matmul", fn, x, x) is False
+        t.observe("step", 0.01)
+        t.observe("matmul", 0.01)
+        t.publish()
+        assert t.analyzed == 0
+        rows = {r["executable"]: r for r in t.snapshot()}
+        for name in ("step", "matmul"):
+            assert "roofline_utilization" not in rows[name]
+            assert rows[name]["compile_s"] > 0  # host-side compile time still lands
+        text = reg.exposition()
+        assert 'exec_wall_seconds_total{executable="step"}' in text
+        assert "exec_roofline_utilization{" not in text
+        assert "exec_analytic_disagreement{" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -821,7 +859,7 @@ class TestLMServiceObs:
         assert [e["type"] for e in fired] == ["fire", "clear"]
         assert obs.alerts.active() == []
 
-    def test_perf_attribution_joins_serve_executables(self, gemma):
+    def test_perf_attribution_joins_serve_executables(self, gemma, chip_peaks):
         obs = Obs()
         svc = self._service(gemma, obs)
         assert svc.engine.perf is obs.perf  # service wires the shared timer

@@ -306,7 +306,7 @@ def test_sharded_serve_matches_local_oracle():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, json
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.decorr import probe_metrics
         from repro.decorr.config import DecorrConfig
@@ -331,7 +331,7 @@ def test_sharded_serve_matches_local_oracle():
             oracle = probe_metrics(z, cfg=cfg_l, perm_key=key)
             f = shard_map(lambda zz, k: probe_metrics(zz, cfg=cfg_g, perm_key=k),
                           mesh=mesh, in_specs=(P("data"), P()), out_specs=P())
-            got = f(z, key)
+            got = jax.jit(f)(z, key)
             out[style] = max(
                 abs(float(oracle[k]) - float(got[k])) / max(abs(float(oracle[k])), 1e-6)
                 for k in oracle

@@ -105,3 +105,16 @@ class TestParseval:
         sq, s0 = sv.sq_sum_and_zeroth_from_freq(g, d)
         np.testing.assert_allclose(sq, jnp.sum(s**2), rtol=1e-5)
         np.testing.assert_allclose(s0, s[0], atol=1e-5)
+
+
+class TestRfftVjp:
+    @pytest.mark.parametrize("d", [8, 9, 16, 33])
+    def test_matches_jax_rfft_transpose(self, d):
+        x = jax.random.normal(jax.random.PRNGKey(0), (3, d))
+        k1, k2 = jax.random.split(jax.random.PRNGKey(1))
+        t = jax.lax.complex(
+            jax.random.normal(k1, (3, d // 2 + 1)), jax.random.normal(k2, (3, d // 2 + 1))
+        )
+        want = jax.vjp(lambda a: jnp.fft.rfft(a, axis=-1), x)[1](t)[0]
+        got = jax.vjp(sv.rfft, x)[1](t)[0]
+        np.testing.assert_allclose(got, want, atol=1e-4)
