@@ -1,0 +1,3 @@
+"""``step_device_ms`` in a host-fed cell, where it moves ``host_fed_samples_per_s``."""
+
+from bench.metrics.step_device_ms import read  # noqa: F401
