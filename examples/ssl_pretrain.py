@@ -28,12 +28,7 @@ from repro.data import SSLDataConfig, ssl_batch
 from repro.decorr import warmup_tune_cache
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for_devices
-from repro.launch.obs_args import (
-    add_obs_args,
-    attach_train_step,
-    build_train_obs,
-    finish_train_obs,
-)
+from repro.launch.obs_args import add_obs_args, build_train_obs, finish_train_obs
 from repro.optim import lars, warmup_cosine
 from repro.train import LoopConfig, create_train_state, run_training
 from repro.train.ssl import (
@@ -175,12 +170,10 @@ def main(argv=None):
         # probe the projector output of view1 — the matrix the decorrelation
         # objective acts on — for collapse / relaxation-gap health
         monitor = DecorrHealthMonitor(lambda params, batch: embed(params, batch["view1"]))
-        attach_train_step(obs, step_fn, state, batch_fn(0))
     state = run_training(
         state, step_fn, batch_fn, lcfg, log_fn=log_fn,
         registry=obs.registry if obs is not None else None,
         monitor=monitor,
-        perf=obs.perf if obs is not None else None,
     )
     finish_train_obs(args, obs)
 

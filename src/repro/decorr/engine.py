@@ -35,6 +35,7 @@ from repro.core import permutation as perm_lib
 from repro.core import regularizers as regs
 from repro.decorr import modes
 from repro.decorr.config import DecorrConfig
+from repro.obs import profiling
 
 Array = jax.Array
 
@@ -170,6 +171,17 @@ def _tp_regularizer(z1: Array, z2: Array, cfg: DecorrConfig, total_scale, perm_k
     return modes.grouped_reg_from_freq(g, int(cfg.block_size), cfg.q)
 
 
+def _route(z1: Array, z2: Array, cfg: DecorrConfig, mode: str, scale, perm_key) -> Array:
+    """R(C) by mode, under the profiler's regularizer scope: every route of
+    every loss passes here."""
+    with jax.named_scope(profiling.REGULARIZER):
+        if mode == "local":
+            return _local_regularizer(z1, z2, cfg, float(scale), perm_key)
+        if mode == "global":
+            return _global_regularizer(z1, z2, cfg, scale, perm_key)
+        return _tp_regularizer(z1, z2, cfg, scale, perm_key)
+
+
 def regularizer(
     z1: Array,
     z2: Array,
@@ -192,7 +204,7 @@ def regularizer(
     """
     mode = effective_mode(cfg)
     if mode == "local":
-        return _local_regularizer(z1, z2, cfg, float(scale), perm_key)
+        return _route(z1, z2, cfg, mode, scale, perm_key)
     if ddof is None:
         total = float(scale) * (
             modes.effective_batch(1, cfg.axis_name) if cfg.axis_name else 1.0
@@ -200,9 +212,7 @@ def regularizer(
     else:
         n_eff = modes.effective_batch(z1.shape[0], _batch_axis(cfg, mode))
         total = max(n_eff - float(ddof), 1.0)
-    if mode == "global":
-        return _global_regularizer(z1, z2, cfg, total, perm_key)
-    return _tp_regularizer(z1, z2, cfg, total, perm_key)
+    return _route(z1, z2, cfg, mode, total, perm_key)
 
 
 # ---------------------------------------------------------------------------
@@ -235,12 +245,7 @@ def barlow_twins(
     if mode == "tp":
         invariance = jax.lax.psum(invariance, cfg.model_axis)
 
-    if mode == "local":
-        reg = _local_regularizer(z1n, z2n, cfg, float(n_local), perm_key)
-    elif mode == "global":
-        reg = _global_regularizer(z1n, z2n, cfg, n_eff, perm_key)
-    else:
-        reg = _tp_regularizer(z1n, z2n, cfg, n_eff, perm_key)
+    reg = _route(z1n, z2n, cfg, mode, n_local if mode == "local" else n_eff, perm_key)
 
     loss = invariance + cfg.lam * reg
     return loss, {"bt_invariance": invariance, "bt_reg": reg, "bt_loss": loss}
@@ -273,15 +278,9 @@ def vicreg(
 
     c1 = center(z1, cfg, mode)
     c2 = center(z2, cfg, mode)
-    if mode == "local":
-        scale = float(max(n_local - 1, 1))
-        reg1 = _local_regularizer(c1, c1, cfg, scale, perm_key)
-        reg2 = _local_regularizer(c2, c2, cfg, scale, perm_key)
-    else:
-        scale = max(n_eff - 1.0, 1.0)
-        route = _global_regularizer if mode == "global" else _tp_regularizer
-        reg1 = route(c1, c1, cfg, scale, perm_key)
-        reg2 = route(c2, c2, cfg, scale, perm_key)
+    scale = float(max(n_local - 1, 1)) if mode == "local" else max(n_eff - 1.0, 1.0)
+    reg1 = _route(c1, c1, cfg, mode, scale, perm_key)
+    reg2 = _route(c2, c2, cfg, mode, scale, perm_key)
 
     d_full = float(d_local)
     if mode == "tp":
@@ -306,7 +305,9 @@ def apply(
     cfg: DecorrConfig,
     perm_key: Optional[Array] = None,
 ) -> Tuple[Array, Dict[str, Array]]:
-    """The engine entry point: full SSL loss for ``cfg.style``."""
-    if cfg.style == "bt":
-        return barlow_twins(z1, z2, cfg, perm_key)
-    return vicreg(z1, z2, cfg, perm_key)
+    """The engine entry point: full SSL loss for ``cfg.style``, under the
+    profiler's loss scope."""
+    with jax.named_scope(profiling.LOSS):
+        if cfg.style == "bt":
+            return barlow_twins(z1, z2, cfg, perm_key)
+        return vicreg(z1, z2, cfg, perm_key)

@@ -18,21 +18,30 @@ from typing import Callable, Dict, Optional
 class StragglerWatchdog:
     """Flags steps slower than ``factor`` x rolling median (straggler
     mitigation hook: at scale the action is to re-shard around the slow
-    host / trigger elastic re-mesh; here we count and expose the signal)."""
+    host / trigger elastic re-mesh; here we count and expose the signal).
 
-    def __init__(self, window: int = 32, factor: float = 3.0, min_samples: int = 8):
+    ``clock`` is injectable (seconds) so tests never sleep."""
+
+    def __init__(
+        self,
+        window: int = 32,
+        factor: float = 3.0,
+        min_samples: int = 8,
+        clock: Callable[[], float] = time.perf_counter,
+    ):
         self.durations: deque = deque(maxlen=window)
         self.factor = factor
         self.min_samples = min_samples
         self.straggler_events = 0
+        self._clock = clock
         self._t0: Optional[float] = None
 
     def step_start(self):
-        self._t0 = time.perf_counter()
+        self._t0 = self._clock()
 
     def step_end(self) -> bool:
         """Returns True if this step was a straggler."""
-        dt = time.perf_counter() - self._t0
+        dt = self._clock() - self._t0
         is_straggler = False
         if len(self.durations) >= self.min_samples:
             med = sorted(self.durations)[len(self.durations) // 2]

@@ -6,8 +6,7 @@ so a training run is scrapeable exactly like a serving one:
 
     obs = build_train_obs(args)                       # None when not asked
     ...
-    run_training(..., registry=obs.registry if obs else None,
-                 perf=obs.perf if obs else None)
+    run_training(..., registry=obs.registry if obs else None)
     finish_train_obs(args, obs)
 
 ``build_train_obs`` returns ``None`` when neither flag was given — default
@@ -47,17 +46,6 @@ def build_train_obs(args) -> Optional["Obs"]:
     return Obs(alerts=AlertManager(default_train_rules() if args.alerts else ()))
 
 
-def attach_train_step(obs, step_fn, state, batch) -> bool:
-    """Best-effort AOT attribution join for the jitted train step (HLO
-    FLOPs/bytes -> roofline gauges).  Never fails the run."""
-    if obs is None:
-        return False
-    try:
-        return obs.perf.attach_jit("train_step", step_fn, state, batch)
-    except Exception:
-        return False
-
-
 def finish_train_obs(args, obs, *, host: str = "127.0.0.1") -> None:
     """Post-run: start the scrape endpoint, self-scrape once (so the run's
     final state is evaluated against the alert rules and visible even in
@@ -75,12 +63,6 @@ def finish_train_obs(args, obs, *, host: str = "127.0.0.1") -> None:
         active = obs.alerts.active()
         print(f"[obs] scraped {series} series from {url}"
               + (f"  ACTIVE ALERTS: {active}" if active else ""))
-        top = obs.perf.snapshot(top_k=3)
-        for row in top:
-            util = row.get("roofline_utilization")
-            extra = f"  util={util:.3g}" if util is not None else ""
-            print(f"[obs]   {row['executable']}: {row['calls']} calls, "
-                  f"total {row['total_s']:.3f}s{extra}")
         if args.metrics_port:
             # a real port was requested: hold the endpoint open briefly so an
             # external scraper pointed at the run can catch the final state

@@ -22,12 +22,7 @@ from repro.core.decorrelation import LMDecorrConfig
 from repro.core.losses import DecorrConfig
 from repro.data import LMDataConfig, lm_batch
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.obs_args import (
-    add_obs_args,
-    attach_train_step,
-    build_train_obs,
-    finish_train_obs,
-)
+from repro.launch.obs_args import add_obs_args, build_train_obs, finish_train_obs
 from repro.models import init_params
 from repro.optim import adamw, warmup_cosine
 from repro.train import LoopConfig, create_train_state, make_train_step, run_training
@@ -125,12 +120,9 @@ def main():
               f"decorr={m.get('decorr_aux', 0):.5f} ({time.time()-t0:.1f}s)")
 
     obs = build_train_obs(args)
-    if obs is not None:
-        attach_train_step(obs, step_fn, state, batch_fn(0))
     state = run_training(
         state, step_fn, batch_fn, lcfg, log_fn=log_fn,
         registry=obs.registry if obs is not None else None,
-        perf=obs.perf if obs is not None else None,
     )
     print(f"[train] done at step {int(state.step)} in {time.time()-t0:.1f}s")
     finish_train_obs(args, obs)

@@ -181,7 +181,7 @@ def _populate_train(obs) -> None:
     run_training(
         _State(), train_step, batch_fn,
         LoopConfig(total_steps=4, log_interval=2),
-        registry=obs.registry, monitor=monitor, perf=obs.perf,
+        registry=obs.registry, monitor=monitor,
     )
 
 

@@ -1,8 +1,8 @@
 """Per-executable device-time attribution with a roofline join.
 
-The serve and train stacks compile a handful of executables (per-bucket
-embedding forwards, per-bucket prefills, the batched decode tick, the
-chunked-prefill step, the probe update, the train step) and until now the
+The serve stack compiles a handful of executables (per-bucket embedding
+forwards, per-bucket prefills, the batched decode tick, the chunked-prefill
+step, the probe update), each blocked on before it is timed, and until now the
 telemetry only gated the AGGREGATE — tok/s — so a regression in one
 executable hid behind the others.  ``ExecTimer`` is the attribution layer:
 

@@ -12,6 +12,40 @@ The cheap always-on counterpart — per-executable step-time histograms for
 prefill / chunked-prefill / decode — lives in the metrics registry
 (``serve_*_seconds``), fed by the service tick; this module only owns the
 heavyweight trace capture.
+
+Names a capture can attribute time by.  They cost nothing when no trace is
+running: device scopes only change HLO metadata, and an inactive host span is
+a no-op of about a microsecond.
+
+* Device scopes (``jax.named_scope``): each compiled instruction's
+  ``op_name`` metadata, and the ``tf_op`` of its event in a TPU trace, carries
+  the path of scopes it was traced under; backward ops carry them inside
+  ``transpose(jvp(...))``.
+
+  ======================  ==================================================
+  ``ENCODER``             ``train/ssl.embed``: backbone and projector
+  ``LOSS``                ``decorr/engine.apply``: normalization,
+                          permutation, invariance and the regularizer
+  ``REGULARIZER``         every route of ``decorr/engine``'s R(C): jnp and
+                          Pallas R_off, FFT and grouped R_sum, the
+                          ``local``/``global``/``tp`` modes
+  ``OPTIMIZER``           ``Optimizer.update``, in every train step
+  ======================  ==================================================
+
+* Host spans (``jax.profiler.TraceAnnotation``) of ``train/loop.run_training``,
+  inside one ``jax.profiler.StepTraceAnnotation("train", step_num=step)`` per
+  iteration:
+
+  ======================  ==================================================
+  ``SPAN_BATCH``          ``batch_fn(step)``
+  ``SPAN_DISPATCH``       the ``train_step`` call (enqueue; blocks only when
+                          the device is behind)
+  ``SPAN_SYNC``           the log interval's device-to-host read of the
+                          step's metrics: waits until the queued steps drain
+  ``SPAN_PUBLISH``        ``log_fn``, registry and health-monitor work at the
+                          log interval
+  ``SPAN_CKPT``           ``CheckpointManager.save``
+  ======================  ==================================================
 """
 
 from __future__ import annotations
@@ -20,6 +54,18 @@ import logging
 from typing import Dict, Optional
 
 log = logging.getLogger("repro.obs.profiling")
+
+ENCODER = "encoder"
+LOSS = "loss"
+REGULARIZER = "regularizer"
+OPTIMIZER = "optimizer"
+
+STEP_NAME = "train"
+SPAN_BATCH = "train.batch"
+SPAN_DISPATCH = "train.dispatch"
+SPAN_SYNC = "train.sync"
+SPAN_PUBLISH = "train.publish"
+SPAN_CKPT = "train.ckpt"
 
 
 class Profiler:

@@ -21,6 +21,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.obs import profiling
+
 Array = jax.Array
 PyTree = Any
 
@@ -30,6 +32,16 @@ class Optimizer:
     init: Callable[[PyTree], PyTree]
     update: Callable[[PyTree, PyTree, PyTree, Array], tuple[PyTree, PyTree]]
     name: str = "optimizer"
+
+    def __post_init__(self):
+        # every train step's update runs under the profiler's optimizer scope
+        update = self.update
+
+        def scoped(*args, **kwargs):
+            with jax.named_scope(profiling.OPTIMIZER):
+                return update(*args, **kwargs)
+
+        object.__setattr__(self, "update", scoped)
 
 
 def _tree_zeros_like(params, dtype=None):

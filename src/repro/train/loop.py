@@ -16,9 +16,11 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, Optional
 
+import jax
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.ft.watchdog import PreemptionSignal, StragglerWatchdog, with_retries
+from repro.obs import profiling
 from repro.train.train_state import TrainState
 
 
@@ -42,20 +44,23 @@ def run_training(
     fault_hook: Optional[Callable[[int], None]] = None,
     registry=None,
     monitor=None,
-    perf=None,
 ) -> TrainState:
     """batch_fn(step) -> device-ready batch (deterministic per step).
     fault_hook(step) may raise RuntimeError to simulate transient faults.
-    ``registry`` (an ``repro.obs.MetricsRegistry``) gets per-phase wall-time
-    histograms (batch fetch / train step / log-interval publish) + a step
+    ``registry`` (an ``repro.obs.MetricsRegistry``) gets per-phase host-time
+    histograms (loop iteration / batch fetch / log-interval publish) + a step
     counter every step, and ``train_``-prefixed gauges of the training
     metrics plus a global param-norm gauge at each log interval (where they
     are already host-synced — never on the hot path).
     ``monitor`` (an ``repro.obs.DecorrHealthMonitor``) probes the current
     params against the step's batch at each log interval, publishing the
     ``train_decorr_*`` health gauges its alert rules read.
-    ``perf`` (an ``repro.obs.ExecTimer``) attributes the train-step
-    executable's wall time per invocation."""
+
+    Each iteration is a ``StepTraceAnnotation`` and each phase a profiler
+    span (``repro.obs.profiling``: batch, dispatch, sync, publish, ckpt);
+    the step's device time per part comes from a capture through the
+    program's named scopes.  The step is asynchronous: only the log
+    interval's sync waits for the device."""
     mgr = (
         CheckpointManager(cfg.ckpt_dir, interval=cfg.ckpt_interval, keep=cfg.ckpt_keep)
         if cfg.ckpt_dir
@@ -63,9 +68,12 @@ def run_training(
     )
     preempt = PreemptionSignal(cfg.preempt_flag) if cfg.preempt_flag else None
     watchdog = StragglerWatchdog()
+    span = jax.profiler.TraceAnnotation
     h_step = c_steps = h_batch = h_publish = None
     if registry is not None:
-        h_step = registry.histogram("train_step_seconds", "one train step wall time")
+        h_step = registry.histogram(
+            "train_step_seconds", "host time per loop iteration: batch fetch and enqueue"
+        )
         c_steps = registry.counter("train_steps_total", "train steps run")
         h_batch = registry.histogram("train_batch_seconds", "batch fetch wall time")
         h_publish = registry.histogram(
@@ -80,65 +88,68 @@ def run_training(
             state = restored
             start_step = step
 
-    # phase timings land in a cell so one_step keeps the (state, metrics)
-    # return contract with_retries wraps
-    phase = {"batch_s": 0.0, "step_s": 0.0}
+    # the batch fetch's time lands in a cell so one_step keeps the
+    # (state, metrics) return contract with_retries wraps
+    phase = {"batch_s": 0.0}
 
     def one_step(step: int, state: TrainState):
         if fault_hook is not None:
             fault_hook(step)
         t0 = time.perf_counter()
-        batch = batch_fn(step)
-        t1 = time.perf_counter()
-        out = train_step(state, batch)
-        t2 = time.perf_counter()
-        phase["batch_s"] = t1 - t0
-        phase["step_s"] = t2 - t1
-        return out
+        with span(profiling.SPAN_BATCH):
+            batch = batch_fn(step)
+        phase["batch_s"] = time.perf_counter() - t0
+        with span(profiling.SPAN_DISPATCH):
+            return train_step(state, batch)
 
     step_with_retry = with_retries(one_step, max_retries=cfg.max_step_retries)
 
+    def save(state: TrainState, force: bool = False):
+        with span(profiling.SPAN_CKPT):
+            mgr.save(int(state.step), state, force=force)
+
     metrics: Dict = {}
     for step in range(start_step, cfg.total_steps):
-        watchdog.step_start()
-        state, metrics = step_with_retry(step, state)
-        watchdog.step_end()
-        if registry is not None:
-            h_step.observe(watchdog.durations[-1])
-            h_batch.observe(phase["batch_s"])
-            c_steps.inc()
-        if perf is not None:
-            perf.observe("train_step", phase["step_s"])
-
-        at_log = (step + 1) % cfg.log_interval == 0
-        if at_log and (log_fn is not None or registry is not None or monitor is not None):
-            t_pub = time.perf_counter()
-            host_metrics = {k: float(v) for k, v in metrics.items()}
-            host_metrics["stragglers"] = watchdog.straggler_events
+        with jax.profiler.StepTraceAnnotation(profiling.STEP_NAME, step_num=step):
+            watchdog.step_start()
+            state, metrics = step_with_retry(step, state)
+            watchdog.step_end()
             if registry is not None:
-                registry.publish(
-                    {f"train_{k}": v for k, v in host_metrics.items()}
-                )
-                registry.gauge("train_step_seconds_median").set(watchdog.median)
-                _publish_param_norm(registry, state)
-            if monitor is not None:
-                monitor.update(state, batch_fn(step), step=step + 1, registry=registry)
-            if log_fn is not None:
-                log_fn(step + 1, host_metrics)
-            if h_publish is not None:
-                h_publish.observe(time.perf_counter() - t_pub)
+                h_step.observe(watchdog.durations[-1])
+                h_batch.observe(phase["batch_s"])
+                c_steps.inc()
 
-        if mgr is not None:
-            mgr.save(int(state.step), state)
+            at_log = (step + 1) % cfg.log_interval == 0
+            if at_log and (log_fn is not None or registry is not None or monitor is not None):
+                t_pub = time.perf_counter()
+                with span(profiling.SPAN_SYNC):
+                    host_metrics = {k: float(v) for k, v in metrics.items()}
+                with span(profiling.SPAN_PUBLISH):
+                    host_metrics["stragglers"] = watchdog.straggler_events
+                    if registry is not None:
+                        registry.publish(
+                            {f"train_{k}": v for k, v in host_metrics.items()}
+                        )
+                        registry.gauge("train_step_seconds_median").set(watchdog.median)
+                        _publish_param_norm(registry, state)
+                    if monitor is not None:
+                        monitor.update(state, batch_fn(step), step=step + 1, registry=registry)
+                    if log_fn is not None:
+                        log_fn(step + 1, host_metrics)
+                    if h_publish is not None:
+                        h_publish.observe(time.perf_counter() - t_pub)
+
+            if mgr is not None:
+                save(state)
 
         if preempt is not None and preempt.raised():
             if mgr is not None:
-                mgr.save(int(state.step), state, force=True)
+                save(state, force=True)
                 mgr.wait()
             break
 
     if mgr is not None:
-        mgr.save(int(state.step), state, force=True)
+        save(state, force=True)
         mgr.wait()
     return state
 

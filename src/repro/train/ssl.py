@@ -27,6 +27,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.losses import DecorrConfig, ssl_loss
+from repro.obs import profiling
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 from repro.parallel import sharding as shd
 from repro.train.train_state import TrainState
@@ -79,7 +80,8 @@ def projector_apply(params: Dict, h: Array) -> Array:
 
 
 def embed(params: Dict, x: Array) -> Array:
-    return projector_apply(params, backbone_apply(params, x))
+    with jax.named_scope(profiling.ENCODER):
+        return projector_apply(params, backbone_apply(params, x))
 
 
 def make_ssl_train_step(

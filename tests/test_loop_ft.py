@@ -4,6 +4,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.data import LMDataConfig, lm_batch
@@ -92,17 +93,20 @@ def test_preemption_checkpoints_and_exits(tmp_path):
 
 
 def test_straggler_watchdog_flags_outliers():
-    import time
+    """Rolling-median outlier detection with an injected clock (no sleeping)."""
+    t = {"now": 0.0}
+    wd = StragglerWatchdog(window=16, factor=3.0, min_samples=4, clock=lambda: t["now"])
 
-    wd = StragglerWatchdog(window=16, factor=3.0, min_samples=4)
-    for i in range(6):
+    def step(seconds):
         wd.step_start()
-        time.sleep(0.002)
-        wd.step_end()
-    wd.step_start()
-    time.sleep(0.05)
-    assert wd.step_end() is True
+        t["now"] += seconds
+        return wd.step_end()
+
+    assert not any(step(0.002) for _ in range(6))
+    assert step(0.005) is False  # 2.5x the median: not an outlier
+    assert step(0.05) is True
     assert wd.straggler_events == 1
+    assert wd.median == pytest.approx(0.002)
 
 
 def test_with_retries_backoff():

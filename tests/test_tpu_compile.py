@@ -147,3 +147,34 @@ def test_sharded_ssl_step_on_four_chips(mosaic, mode, shape, axes):
         hlo = _compile_text(loss_and_grads, params, {"view1": view, "view2": view}, rng)
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+
+
+def test_ssl_step_kernels_run_under_the_regularizer_scope(one_chip):
+    """The grouped R_sum's Mosaic calls in the whole SSL step keep
+    ``r_sum_kernel`` in their instruction names (the name a trace shows) and
+    carry the program's ``regularizer`` scope, forward and backward."""
+    import re
+
+    from repro.decorr.config import DecorrConfig
+    from repro.optim import lars, warmup_cosine
+    from repro.train import create_train_state
+    from repro.train.ssl import SSLModelConfig, init_ssl_params, make_ssl_train_step
+
+    model = SSLModelConfig(input_dim=128, backbone_widths=(128,), projector_widths=(1024,))
+    cfg = DecorrConfig(style="bt", reg="sum", q=2, block_size=128, lam=2.0**-10, permute=True,
+                       use_kernel=True)
+    opt = lars()
+    step_fn, _ = make_ssl_train_step(model, cfg, opt, warmup_cosine(0.2, 3, 30))
+    state = jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: create_train_state(init_ssl_params(jax.random.PRNGKey(0), model), opt)),
+    )
+    view = _spec((64, model.input_dim), jnp.float32, one_chip)
+    hlo = _compile_text(step_fn, state, {"view1": view, "view2": view})
+    calls = [ln for ln in hlo.splitlines() if " custom-call(" in ln and "tpu_custom_call" in ln]
+    names = [re.match(r"\s*%?(\S+) = ", ln).group(1) for ln in calls]
+    assert calls and all("r_sum_kernel" in n for n in names)
+    op_names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    assert all("/regularizer/jit(r_sum_kernel)/" in op for op in op_names)
+    assert any(op.startswith("jit(train_step)/jvp(loss)/") for op in op_names)
+    assert any(op.startswith("jit(train_step)/transpose(jvp(loss))/") for op in op_names)
