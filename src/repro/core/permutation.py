@@ -49,5 +49,7 @@ def permute_views(
     d = z1.shape[-1]
     perm = jax.random.permutation(key, d)
     z1p = permute_features(z1, perm)
+    if z2 is z1:  # one view (VICReg's R of one view) stays one array
+        return z1p, z1p
     z2p = permute_features(z2, perm) if z2 is not None else None
     return z1p, z2p

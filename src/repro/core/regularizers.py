@@ -136,11 +136,14 @@ def r_sum_grouped(
     impl = _resolve_impl("r_sum_grouped", q, impl)
     # b > d means "pad d up to b" here (matching the matrix oracle), but the
     # Pallas kernel clamps b to d — route the degenerate case through jnp on
-    # every backend so the loss value never depends on hardware.
-    if impl == "pallas" and b <= z1.shape[-1]:
-        from repro.kernels.grouped_sumvec import ops as gops
+    # every backend so the loss value never depends on hardware.  So do
+    # shapes whose batch tile the kernels cannot hold in VMEM.
+    from repro.kernels.grouped_sumvec import ops as gops
 
-        return gops.r_sum_kernel(z1, z2, block_size=b, q=q, scale=s)
+    d = z1.shape[-1]
+    if impl == "pallas" and b <= d and gops.fits(d, b):
+        # one view twice (VICReg): the kernel transforms it once
+        return gops.r_sum_kernel(z1, None if z2 is z1 else z2, block_size=b, q=q, scale=s)
     g = sv.grouped_frequency_accumulator(z1, z2, b) / s  # (nb, nb, nf)
     nb = g.shape[0]
     eye = jnp.eye(nb, dtype=jnp.float32)

@@ -119,9 +119,9 @@ def frequency_accumulator(
         from repro.tune import dispatch as tune_dispatch
 
         impl = tune_dispatch.best_impl("r_sum_grouped")
-    if impl == "pallas" and b <= d:
-        from repro.kernels.grouped_sumvec import ops as gops
+    from repro.kernels.grouped_sumvec import ops as gops
 
+    if impl == "pallas" and b <= d and gops.fits(d, b):
         g_r, g_i = gops.grouped_frequency_accumulator_kernel(z1, z2, b)
         # kernel layout (nf, nb, nb) -> core layout (nb, nb, nf)
         return jnp.transpose(jax.lax.complex(g_r, g_i), (1, 2, 0))

@@ -26,14 +26,21 @@ LANE = 128
 SUBLANE = 8
 
 
-def dot_f32(a, b):
+def dot_f32(a, b, contract=((1,), (0,))):
     """In-kernel f32 matmul at full f32 precision.
+
+    ``contract`` names the contracted axis of each 2-D operand: the default is
+    ``a @ b``; ``((1,), (1,))`` is ``a @ b.T`` and ``((0,), (0,))`` is
+    ``a.T @ b``.
 
     Mosaic's default contracts f32 operands at reduced precision; through the
     DFT bases that biased the grouped R_sum on a v5e by +1.1e-3 relative to
     the exact matrix form (the jnp FFT route: 2e-6).
     """
-    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def interpret() -> bool:
@@ -91,23 +98,17 @@ def pad_axis(x, axis: int, target: int):
     return jnp.pad(x, pad)
 
 
-def pad_to_tiles(x, tile_by_axis: dict[int, int]):
-    for axis, tile in tile_by_axis.items():
-        x = pad_axis(x, axis, next_multiple(x.shape[axis], tile))
-    return x
-
-
-def dft_matrices(d: int, dtype=jnp.float32):
+def dft_matrices(d: int):
     """Real/imag rfft basis: F[f] = sum_t z[t] * (Cr[t,f] + i Ci[t,f]).
 
     Cr[t, f] = cos(2 pi t f / d);  Ci[t, f] = -sin(2 pi t f / d).
-    Shapes (d, d//2 + 1).
+    Shapes (d, d//2 + 1), NumPy float32: kernels lay them out at trace time.
     """
     nf = d // 2 + 1
     t = np.arange(d)[:, None]
     f = np.arange(nf)[None, :]
     ang = 2.0 * np.pi * t * f / d
-    return jnp.asarray(np.cos(ang), dtype), jnp.asarray(-np.sin(ang), dtype)
+    return np.cos(ang).astype(np.float32), (-np.sin(ang)).astype(np.float32)
 
 
 def full_dft_matrices(d: int, sign: int = -1, dtype=jnp.float32):

@@ -77,20 +77,17 @@ def jobs_for(n: int, d: int, block_size=None, forward_only=False, **tune_kw):
         ("cmatmul", (d1, d2, d2)),
         ("cmatmul", (d2, d1, d1)),
         ("ctwiddle", (1, dp)),
-        # grouped pipeline: block DFT fwd + pairwise stage
-        ("pmatmul", (n * nb, b, 2 * nf)),
-        ("pmatmul", (nb * nb, nf, b)),  # q = 1 synthesis
-        ("freq_outer", (nf, 2 * n, nb)),
-        ("freq_mat", (nf, 2 * n, nb, nb)),
+        # grouped pipeline: block DFTs + per-frequency Gram, then q = 1's
+        # synthesis of the summary vectors
+        ("spectral_gram", (n, d, b)),
+        ("pmatmul", (nb * nb, nf, b)),
     ]
     if not forward_only:
         jobs += [
             # four-step vjp: dB = A^H @ g shapes from _cmm_bwd
             ("cmatmul", (d1, n * d2, d1)),
             ("cmatmul", (d2, n * d1, d2)),
-            # grouped block-DFT vjp pair
-            ("pmatmul", (n * nb, 2 * nf, b)),
-            ("pmatmul", (b, n * nb, 2 * nf)),
+            ("spectral_gram_vjp", (n, d, b)),
         ]
     # distinct canonical shapes only (small d collapses several of these)
     seen, uniq = set(), []

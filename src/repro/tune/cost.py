@@ -77,22 +77,10 @@ def analytic_cost(kernel: str, shape: Shape, cfg: Config) -> Dict[str, float]:
         grid = npd // tn
         flops = 6.0 * npd * dp
         hbm = F32 * (4.0 * npd * dp + 2.0 * dp * grid)
-    elif kernel == "freq_outer":
-        f, k, n = shape
-        tk, tn = cfg["tk"], cfg["tn"]
-        npad = next_multiple(n, LANE)
-        kp = next_multiple(k, tk)
-        grid = f * (npad // tn) * (kp // tk)
-        flops = 2.0 * f * npad * npad * kp
-        hbm = F32 * f * (kp * npad * (npad / tn) + kp * npad + npad * npad)
-    elif kernel == "freq_mat":
-        f, k, n, n2 = shape
+    elif kernel in ("spectral_gram", "spectral_gram_vjp"):
+        n, d, b = shape
         tk = cfg["tk"]
-        npad, n2pad = next_multiple(n, LANE), next_multiple(n2, LANE)
-        kp = next_multiple(k, tk)
-        grid = f * (kp // tk)
-        flops = 2.0 * f * kp * npad * n2pad
-        hbm = F32 * f * (kp * npad + npad * n2pad * (kp / tk) + kp * n2pad)
+        flops, hbm, grid = _spectral_gram_cost(n, d, b, tk, vjp=kernel == "spectral_gram_vjp")
     elif kernel == "paged_attention":
         b, s, h, hd = shape
         page = cfg["page"]
@@ -106,19 +94,12 @@ def analytic_cost(kernel: str, shape: Shape, cfg: Config) -> Dict[str, float]:
         hbm = F32 * b * (2.0 * sp * hp * hdp + 2.0 * nb * hp * hdp)
     elif kernel == "grouped_block_plan":
         n, d = shape
-        b = cfg["b"]
-        nb = _cdiv(d, b)
-        nf = b // 2 + 1
-        # block DFT forward, both views: (n*nb, b) @ (b, 2*nf) per view
-        flops = 2.0 * 2.0 * (n * nb) * b * (2.0 * nf)
-        hbm = F32 * 2.0 * (n * nb * b + b * 2 * nf + n * nb * 2 * nf)
-        # pairwise frequency-outer stage on the LANE-padded group axis —
-        # tiny nb pays full-tile padding, which is exactly what makes very
-        # small b lose despite its lower DFT flops
-        npad = next_multiple(nb, LANE)
-        flops += 2.0 * nf * (2.0 * n) * npad * npad
-        hbm += F32 * nf * (2.0 * n * npad + npad * npad)
-        grid = _cdiv(n * nb, SUBLANE) + nf
+        # the forward pass at the default batch tile: block DFTs of both
+        # views plus the per-frequency Gram of the (2 nb)-row stacked spectra,
+        # which the MXU pads to full 128-row tiles — tiny nb pays that
+        # padding, which is what makes very small b lose despite its lower
+        # DFT flops
+        flops, hbm, grid = _spectral_gram_cost(n, d, cfg["b"], min(128, next_multiple(n, LANE)))
     elif kernel == "sumvec_fft_plan":
         (d,) = shape
         dp, d1, d2 = cfg["dp"], cfg["d1"], cfg["d2"]
@@ -142,6 +123,26 @@ def analytic_cost(kernel: str, shape: Shape, cfg: Config) -> Dict[str, float]:
         "grid_steps": float(grid),
         "vmem_bytes": float(vmem_bytes(kernel, shape, cfg)),
     }
+
+
+def _spectral_gram_cost(n: int, d: int, b: int, tk: int, vjp: bool = False):
+    """(flops, hbm_bytes, grid_steps) of ``spectral_gram`` (or its vjp) for
+    two views, from the kernel's layout.  Forward, per batch tile: the block
+    DFTs of both views and one (2 nbp)-square Gram per frequency; it reads Z
+    and writes the spectra.  The vjp reads the spectra: two cotangent
+    products per frequency and the inverse DFTs, writing dZ."""
+    from repro.kernels.grouped_sumvec.kernel import layout
+
+    lay = layout(d, b)
+    tiles = _cdiv(n, tk)
+    npd = tiles * tk
+    m = next_multiple(2 * lay.nbp, LANE)
+    dft = 2.0 * 2.0 * npd * lay.chunks * (2 * lay.per * lay.rh) * lay.width
+    gram = 2.0 * lay.nf * m * m * npd
+    hbm = F32 * (2.0 * n * d + 2.0 * npd * lay.rows + lay.nf * m * m)
+    if vjp:
+        return dft + 2.0 * gram, hbm, tiles
+    return dft + gram + 2.0 * 2.0 * lay.nf * m**3, hbm, tiles
 
 
 def rank_key(cost: Dict[str, float], kernel: str = "") -> Tuple[float, float, float]:

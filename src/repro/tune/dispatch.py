@@ -42,8 +42,8 @@ _CANON_UNITS = {
     "cmatmul": (SUBLANE, LANE, LANE),
     "pmatmul": (SUBLANE, LANE, LANE),
     "ctwiddle": (SUBLANE, LANE),
-    "freq_outer": (None, SUBLANE, LANE),
-    "freq_mat": (None, SUBLANE, LANE, LANE),
+    "spectral_gram": (LANE, None, None),
+    "spectral_gram_vjp": (LANE, None, None),
     "sumvec_fft_plan": (None,),
     "grouped_block_plan": (None, None),
     "paged_attention": (None, SUBLANE, SUBLANE, LANE),
@@ -68,7 +68,7 @@ def _analytic_search(kernel: str, shape: Tuple[int, ...]) -> Config:
     cands = _space.candidates(kernel, shape)
     if not cands:
         # Some shapes have a config-independent VMEM term that alone busts
-        # the budget (e.g. freq_mat's full (npad, n2pad) operand block), so
+        # the budget (e.g. spectral_gram's spectra of every block), so
         # no candidate is "legal".  These shapes always ran with the clamped
         # hardwired tiles before tuning existed — keep running them.
         return _space.default_config(kernel, shape)

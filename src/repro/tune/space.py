@@ -23,8 +23,8 @@ Kernel names and their shape/config conventions:
   cmatmul            (m, k, n)             tm, tn, tk
   ctwiddle           (n, d)                tn
   pmatmul            (m, k, n)             tm, tn, tk
-  freq_outer         (f, k, n)             tk, tn
-  freq_mat           (f, k, n, n2)         tk
+  spectral_gram      (n, d, b)             tk           (batch rows per tile)
+  spectral_gram_vjp  (n, d, b)             tk
   sumvec_fft_plan    (d,)                  dp, d1, d2   (dp > d => padded)
   grouped_block_plan (n, d)                b            (block DFT group size)
   paged_attention    (b, s, kv, hd)        page         (KV tokens per block)
@@ -32,7 +32,7 @@ Kernel names and their shape/config conventions:
 ``grouped_block_plan`` is a *plan* kernel like ``sumvec_fft_plan``: its
 config is the grouped regularizer's block size b itself (searched over
 ``grouped_block_size_candidates`` instead of fixed by the caller), and the
-pipeline it selects delegates all tiling to pmatmul/freq_outer/freq_mat.
+pipeline it selects delegates all tiling to spectral_gram/spectral_gram_vjp.
 NOTE: b is part of the LOSS definition — plan-tuning it is for perf studies
 and serve probes where any legal b computes a valid health signal; training
 configs that pin b for accuracy reasons must keep passing it explicitly.
@@ -48,10 +48,18 @@ from repro.kernels.pallas_utils import LANE, SUBLANE, next_multiple
 Config = Dict[str, int]
 Shape = Tuple[int, ...]
 
-VMEM_BYTES = 16 * 2**20
+# VMEM of one core of the target chip (launch.hlo_cost.TARGET_DEVICE_KIND,
+# TPU v5e); a kernel may raise its scoped limit up to this.
+VMEM_BYTES = 128 * 2**20
 # Working-set ceiling for one kernel instance (inputs/outputs double-buffered
-# + scratch).  3/4 of VMEM leaves room for compiler spills and semaphores.
+# + scratch) under the compiler's default scoped limit, 16 MiB: 3/4 of it
+# leaves room for compiler spills and semaphores.
 VMEM_BUDGET_BYTES = 12 * 2**20
+# The grouped R_sum kernels hold whole feature rows of a batch tile and their
+# spectra in VMEM: they raise their scoped limit (``vmem_limit_bytes``, the
+# need plus a quarter) and are held to this ceiling instead, which leaves the
+# limit under VMEM_BYTES.
+GROUPED_VMEM_BUDGET_BYTES = 80 * 2**20
 
 F32 = 4  # bytes; all kernels accumulate in f32
 
@@ -63,8 +71,8 @@ KERNELS = (
     "cmatmul",
     "ctwiddle",
     "pmatmul",
-    "freq_outer",
-    "freq_mat",
+    "spectral_gram",
+    "spectral_gram_vjp",
     "sumvec_fft_plan",
     "grouped_block_plan",
     "paged_attention",
@@ -97,15 +105,20 @@ def vmem_bytes(kernel: str, shape: Shape, cfg: Config) -> int:
         tn = cfg["tn"]
         dp = next_multiple(shape[1], LANE)
         return 2 * (4 * tn * dp + 2 * dp) * F32
-    if kernel == "freq_outer":
-        tk, tn = cfg["tk"], cfg["tn"]
-        npad = next_multiple(shape[2], LANE)
-        return 2 * (tk * npad + tk * tn + npad * tn) * F32
-    if kernel == "freq_mat":
-        tk = cfg["tk"]
-        npad = next_multiple(shape[2], LANE)
-        n2pad = next_multiple(shape[3], LANE)
-        return 2 * (tk * npad + npad * n2pad + tk * n2pad) * F32
+    if kernel in ("spectral_gram", "spectral_gram_vjp"):
+        from repro.kernels.grouped_sumvec.kernel import layout
+
+        _, d, b = shape
+        tk, lay = cfg["tk"], layout(d, b)
+        m = 2 * lay.nbp
+        # both views, double-buffered: Z tiles (dZ in the vjp) and spectra
+        tiles = 2 * 2 * tk * (lay.dp + lay.rows)
+        gram = 2 * lay.nf * next_multiple(m, SUBLANE) * next_multiple(m, LANE)
+        # the chunk basis: its double-buffered block and the value loaded from it
+        basis = 3 * 2 * lay.per * lay.rh * lay.width
+        # the vjp's cotangent spectra
+        scratch = 2 * lay.rows * tk if kernel == "spectral_gram_vjp" else 0
+        return (tiles + gram + basis + scratch) * F32
     if kernel in ("sumvec_fft_plan", "grouped_block_plan"):
         # plans delegate all blocking to the matmul/twiddle kernels they
         # select; their own VMEM footprint is whatever those choose.
@@ -141,8 +154,8 @@ def is_legal(kernel: str, shape: Shape, cfg: Config) -> bool:
         "cmatmul": ("tn", "tk"),
         "pmatmul": ("tn", "tk"),
         "ctwiddle": (),
-        "freq_outer": ("tn",),
-        "freq_mat": (),
+        "spectral_gram": ("tk",),
+        "spectral_gram_vjp": ("tk",),
         "paged_attention": (),
     }[kernel]
     sub_keys = {
@@ -150,8 +163,8 @@ def is_legal(kernel: str, shape: Shape, cfg: Config) -> bool:
         "cmatmul": ("tm",),
         "pmatmul": ("tm",),
         "ctwiddle": ("tn",),
-        "freq_outer": ("tk",),
-        "freq_mat": ("tk",),
+        "spectral_gram": (),
+        "spectral_gram_vjp": (),
         "paged_attention": ("page",),
     }[kernel]
     for k in lane_keys:
@@ -160,7 +173,8 @@ def is_legal(kernel: str, shape: Shape, cfg: Config) -> bool:
     for k in sub_keys:
         if cfg[k] <= 0 or cfg[k] % SUBLANE:
             return False
-    return vmem_bytes(kernel, shape, cfg) <= VMEM_BUDGET_BYTES
+    budget = GROUPED_VMEM_BUDGET_BYTES if kernel.startswith("spectral_gram") else VMEM_BUDGET_BYTES
+    return vmem_bytes(kernel, shape, cfg) <= budget
 
 
 # ---------------------------------------------------------------------------
@@ -233,14 +247,9 @@ def candidates(kernel: str, shape: Shape) -> List[Config]:
         n, d = shape
         for tn in _tile_options(n, SUBLANE, _SUBLANE_TILES):
             out.append({"tn": tn})
-    elif kernel == "freq_outer":
-        f, k, n = shape
-        for tk in _tile_options(k, SUBLANE, _SUBLANE_TILES):
-            for tn in _tile_options(next_multiple(n, LANE), LANE, _LANE_TILES):
-                out.append({"tk": tk, "tn": tn})
-    elif kernel == "freq_mat":
-        f, k, n, n2 = shape
-        for tk in _tile_options(k, SUBLANE, _SUBLANE_TILES):
+    elif kernel in ("spectral_gram", "spectral_gram_vjp"):
+        n, d, b = shape
+        for tk in _tile_options(n, LANE, _LANE_TILES):
             out.append({"tk": tk})
     elif kernel == "sumvec_fft_plan":
         (d,) = shape
@@ -281,15 +290,9 @@ def default_config(kernel: str, shape: Shape) -> Config:
     if kernel == "ctwiddle":
         n, d = shape
         return {"tn": min(128, next_multiple(n, SUBLANE))}
-    if kernel == "freq_outer":
-        f, k, n = shape
-        return {
-            "tk": min(128, next_multiple(k, SUBLANE)),
-            "tn": min(128, next_multiple(n, LANE)),
-        }
-    if kernel == "freq_mat":
-        f, k, n, n2 = shape
-        return {"tk": min(128, next_multiple(k, SUBLANE))}
+    if kernel in ("spectral_gram", "spectral_gram_vjp"):
+        n, d, b = shape
+        return {"tk": min(128, next_multiple(n, LANE))}
     if kernel == "sumvec_fft_plan":
         (d,) = shape
         d1, d2 = balanced_factors(d)

@@ -101,18 +101,19 @@ def _build(kernel: str, shape: Tuple[int, ...], cfg: Config) -> Tuple[Callable, 
         m, k, n = shape
         fn = lambda a, b: _pmatmul_raw(a, b, tm=cfg["tm"], tn=cfg["tn"], tk=cfg["tk"])
         return fn, _ones((m, k), (k, n))
-    if kernel == "freq_outer":
-        from repro.kernels.grouped_sumvec.kernel import _freq_outer_raw
+    if kernel in ("spectral_gram", "spectral_gram_vjp"):
+        from repro.kernels.grouped_sumvec import kernel as gk
 
-        f, k, n = shape
-        fn = lambda a, b: _freq_outer_raw(a, b, tk=cfg["tk"], tn=cfg["tn"])
-        return fn, _ones((f, k, n), (f, k, n))
-    if kernel == "freq_mat":
-        from repro.kernels.grouped_sumvec.kernel import _freq_mat_raw
-
-        f, k, n, n2 = shape
-        fn = lambda a, m_: _freq_mat_raw(a, m_, tk=cfg["tk"])
-        return fn, _ones((f, k, n), (f, n, n2))
+        n, d, b = shape
+        if kernel == "spectral_gram":
+            fn = lambda z1, z2: gk.spectral_gram((z1, z2), b, tk=cfg["tk"])
+            return fn, _ones((n, d), (n, d))
+        lay = gk.layout(d, b)
+        m, spec = 2 * lay.nbp, (-(-n // gk.LANE), lay.rows, gk.LANE)
+        fn = lambda z1, z2, s1, s2, h, c, u2: gk.spectral_gram_vjp(
+            (z1, z2), (s1, s2), h, c, u2, b, tk=cfg["tk"]
+        )
+        return fn, _ones((n, d), (n, d), spec, spec, (lay.nf, m, m), (lay.nf,), (m,))
     if kernel == "paged_attention":
         from repro.kernels.paged_attention.ops import paged_decode_attention_raw
 

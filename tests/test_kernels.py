@@ -25,6 +25,13 @@ GROUPED_CASES = [
     (4, 64, 16, 2), (32, 24, 24, 2), (5, 33, 8, 2),
 ]
 
+# b divides d, b does not (a zero-padded last block), b = d (ungrouped), and a
+# batch past one 128-row tile (the last tile masked)
+PARITY_CASES = [
+    (16, 64, 16, 1), (16, 64, 16, 2), (12, 40, 16, 1), (12, 40, 16, 2),
+    (8, 24, 24, 1), (8, 24, 24, 2), (136, 256, 128, 2),
+]
+
 
 class TestGroupedSumvecKernel:
     @pytest.mark.parametrize("n,d,b,q", GROUPED_CASES)
@@ -56,6 +63,62 @@ class TestGroupedSumvecKernel:
         got = gops.r_sum_kernel(z1, z2, block_size=None, q=2, scale=8)
         want = gref.r_sum_ref(z1, z2, q=2, scale=8)
         np.testing.assert_allclose(got, want, rtol=1e-4)
+
+    @pytest.mark.parametrize("n,d,b,q", PARITY_CASES)
+    def test_value_and_grads_match_jnp_and_matrix_routes(self, n, d, b, q):
+        """The Pallas pipeline against the jnp FFT route (``core/sumvec``) and
+        against Eq. (13) on the explicit matrix C, values and gradients."""
+        z1, z2 = _views(n, d, seed=12)
+        pallas = lambda a, c: gops.r_sum_kernel(a, c, block_size=b, q=q, scale=n)
+        jnp_fft = lambda a, c: regs.r_sum_grouped(a, c, b, q=q, scale=n, impl="jnp")
+        matrix = lambda a, c: regs.r_sum_grouped_from_matrix(
+            regs.cross_correlation_matrix(a, c, scale=n), b, q=q)
+        got, got_g = jax.value_and_grad(pallas, argnums=(0, 1))(z1, z2)
+        for route in (jnp_fft, matrix):
+            want, want_g = jax.value_and_grad(route, argnums=(0, 1))(z1, z2)
+            np.testing.assert_allclose(got, want, rtol=1e-4)
+            for g, w in zip(got_g, want_g):
+                np.testing.assert_allclose(g, w, atol=1e-4 * float(jnp.max(jnp.abs(w))))
+
+    @pytest.mark.parametrize("q", [1, 2])
+    def test_one_view_twice(self, q):
+        """VICReg's R of one view: ``z2=None`` (the route ``r_sum_grouped``
+        takes for ``z1 is z2``) against the jnp route on (z, z)."""
+        n, d, b = 12, 40, 16
+        z, _ = _views(n, d, seed=13)
+        want, want_g = jax.value_and_grad(
+            lambda a: regs.r_sum_grouped(a, a, b, q=q, scale=n, impl="jnp"))(z)
+        for fn in (lambda a: gops.r_sum_kernel(a, None, block_size=b, q=q, scale=n),
+                   lambda a: regs.r_sum_grouped(a, a, b, q=q, scale=n, impl="pallas")):
+            got, got_g = jax.value_and_grad(fn)(z)
+            np.testing.assert_allclose(got, want, rtol=1e-4)
+            np.testing.assert_allclose(got_g, want_g, atol=1e-4 * float(jnp.max(jnp.abs(want_g))))
+
+    @pytest.mark.parametrize("n,d,b,same", [(16, 64, 16, False), (12, 40, 16, False), (12, 40, 16, True)])
+    def test_frequency_accumulator_pallas_matches_jnp(self, n, d, b, same):
+        """``modes.frequency_accumulator``, the statistic the ``global`` and
+        ``tp`` modes psum: the Pallas route against the jnp route, value and
+        the gradient of a fixed linear read-out of it."""
+        from repro.decorr import modes
+
+        z1, z2 = _views(n, d, seed=14)
+        if same:
+            z2 = z1
+        wr, wi = _views(-(-d // b), (-(-d // b)) * (b // 2 + 1), seed=15)
+        shape = (-(-d // b), -(-d // b), b // 2 + 1)
+
+        def read(impl):
+            def fn(a, c):
+                g = modes.frequency_accumulator(a, a if same else c, b, impl=impl)
+                return jnp.sum(g.real * wr.reshape(shape)) + jnp.sum(g.imag * wi.reshape(shape)), g
+            return fn
+
+        (got, got_acc), got_g = jax.value_and_grad(read("pallas"), argnums=(0, 1), has_aux=True)(z1, z2)
+        (want, want_acc), want_g = jax.value_and_grad(read("jnp"), argnums=(0, 1), has_aux=True)(z1, z2)
+        np.testing.assert_allclose(got_acc, want_acc, atol=1e-4 * float(jnp.max(jnp.abs(want_acc))))
+        np.testing.assert_allclose(got, want, rtol=1e-4)
+        for g, w in zip(got_g, want_g):
+            np.testing.assert_allclose(g, w, atol=1e-4 * float(jnp.max(jnp.abs(w))))
 
 
 FOURSTEP_CASES = [(4, 12), (8, 24), (16, 36), (8, 64), (3, 25)]

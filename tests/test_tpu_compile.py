@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import pallas_utils
@@ -77,6 +78,66 @@ def test_grouped_sumvec_fwd_and_grad(one_chip):
     z = _spec((N, D), jnp.float32, one_chip)
     loss = lambda a, b: gops.r_sum_kernel(a, b, block_size=128, q=2, scale=float(N))
     assert "tpu_custom_call" in _compile_text(_value_and_grads(loss), z, z)
+
+
+@pytest.mark.parametrize("q,one_view", [(1, False), (2, True), (1, True)])
+def test_grouped_sumvec_q1_and_one_view(one_chip, q, one_view):
+    """q=1 (the Gram's cotangent from the synthesis) and one view passed once
+    (VICReg's R of one view: the one-view kernels)."""
+    from repro.kernels.grouped_sumvec import ops as gops
+
+    z = _spec((N, D), jnp.float32, one_chip)
+    if one_view:
+        fn = jax.value_and_grad(lambda a: gops.r_sum_kernel(a, None, block_size=128, q=q, scale=float(N)))
+        text = _compile_text(fn, z)
+    else:
+        loss = lambda a, b: gops.r_sum_kernel(a, b, block_size=128, q=q, scale=float(N))
+        text = _compile_text(_value_and_grads(loss), z, z)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("d,b,fits", [(8192, 1024, True), (8192, 2048, False), (16384, 128, False)])
+def test_grouped_sumvec_routes_by_vmem(one_chip, d, b, fits):
+    """The largest chunk basis the kernels take compiles within Mosaic's VMEM;
+    a larger one, or rows too wide for a batch tile, take the jnp FFT route."""
+    from repro.core import regularizers as regs
+    from repro.kernels.grouped_sumvec import ops as gops
+
+    assert gops.fits(d, b) == fits
+    z = _spec((N, d), jnp.float32, one_chip)
+    loss = lambda a, c: regs.r_sum_grouped(a, c, b, q=2, scale=float(N), impl="pallas")
+    assert ("tpu_custom_call" in _compile_text(_value_and_grads(loss), z, z)) == fits
+
+
+@pytest.mark.parametrize("n", [256, 2048])
+def test_grouped_sumvec_moves_no_glue(one_chip, n):
+    """The grouped R_sum's layout contract: between its Pallas calls nothing of
+    the order of Z is relaid out.  No compiled instruction traced under
+    ``jit(r_sum_kernel)``, other than the Mosaic calls (and the tuple elements
+    that name their outputs), produces n * d / 8 elements or more."""
+    import re
+
+    from repro.kernels.grouped_sumvec import ops as gops
+
+    z = _spec((n, D), jnp.float32, one_chip)
+    loss = lambda a, b: gops.r_sum_kernel(a, b, block_size=128, q=2, scale=float(n))
+    hlo = _compile_text(_value_and_grads(loss), z, z)
+    glue, fused = [], False
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():  # a computation's header
+            fused = line.startswith("%fused")  # a fusion's body writes nothing
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%?(\S+) = (.*?) ([\w-]+)\(", line)
+        if fused or not m or "jit(r_sum_kernel)" not in line or "tpu_custom_call" in line:
+            continue
+        if m.group(3) in ("get-tuple-element", "tuple", "bitcast"):
+            continue
+        shapes = re.findall(r"\w+\[([\d,]*)\]", m.group(2))
+        elements = max(int(np.prod([int(x) for x in s.split(",") if x])) for s in shapes)
+        if elements >= n * D // 8:
+            glue.append((m.group(1), m.group(3), m.group(2)))
+    assert "tpu_custom_call" in hlo
+    assert not glue, glue
 
 
 def test_sumvec_fft_fourstep_fwd_and_grad(one_chip):

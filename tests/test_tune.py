@@ -25,8 +25,8 @@ SHAPES = {
     "cmatmul": (40, 24, 72),
     "pmatmul": (40, 24, 72),
     "ctwiddle": (24, 200),
-    "freq_outer": (9, 48, 24),
-    "freq_mat": (9, 48, 24, 24),
+    "spectral_gram": (24, 48, 8),
+    "spectral_gram_vjp": (24, 48, 8),
     "sumvec_fft_plan": (101,),
     "grouped_block_plan": (24, 48),
     "paged_attention": (4, 48, 2, 16),
@@ -211,14 +211,14 @@ class TestDispatch:
         assert tspace.is_legal("xcorr_offdiag", canon, cfg)
 
     def test_no_legal_candidates_falls_back_to_default(self):
-        # freq_mat's full (npad, n2pad) operand block alone busts the VMEM
-        # budget at nb = 2048 — there is no "legal" candidate, but the
-        # kernel must keep running with the clamped legacy default (it did
-        # before tuning existed).
-        shape = (2, 16, 2048, 2048)
-        assert tspace.candidates("freq_mat", shape) == []
-        cfg = tune.best_config("freq_mat", shape)
-        assert cfg == tspace.default_config("freq_mat", tune.canonical_shape("freq_mat", shape))
+        # spectral_gram's whole feature rows of a 128-row tile alone bust the
+        # VMEM budget at d = 2**17 — there is no "legal" candidate, and
+        # dispatch still answers with the default tile (the grouped R_sum
+        # routes such shapes to the jnp FFT: grouped_sumvec.ops.fits).
+        shape = (128, 2**17, 128)
+        assert tspace.candidates("spectral_gram", shape) == []
+        cfg = tune.best_config("spectral_gram", shape)
+        assert cfg == tspace.default_config("spectral_gram", tune.canonical_shape("spectral_gram", shape))
 
     def test_best_impl(self):
         assert tune.best_impl("r_sum", backend="tpu") == "pallas"
@@ -469,9 +469,7 @@ class TestGroupedBlockPlan:
         b = plans[-1].best["b"]
         assert b in tspace.grouped_block_size_candidates(16)
         # the searched winner drives the derived grouped shapes
-        nb = -(-16 // b)
-        nf = b // 2 + 1
-        assert ("pmatmul", (16 * nb, b, 2 * nf)) in jobs
+        assert ("spectral_gram", (16, 16, b)) in jobs
         # a caller-pinned b skips the search entirely (b is loss-defining)
         plans_pinned, _ = jobs_for(16, 16, block_size=8, mode="analytic", persist=False)
         assert [p.kernel for p in plans_pinned] == ["sumvec_fft_plan"]
