@@ -9,10 +9,11 @@ the plain reference (``sound``).  It also reads, against the same reference:
 
 - ``control``: the reference itself in the program's place, computed one
   precision below the configuration's (bfloat16 in place of float32);
-- ``half_batch``: the program with half of each batch left out, the mean taken
-  over the rest;
-- ``reg_grad_zero`` and ``reg_grad_double``: the program with the gradient that
-  flows through its regularizer zeroed or doubled, its loss unchanged.
+- each fault of the cell's program module (``faults(prog, n)``), on the fault
+  seeds; for ``ssl_mlp``: ``half_batch``, half of each batch left out and the
+  mean taken over the rest, and ``reg_grad_zero`` and ``reg_grad_double``, the
+  gradient that flows through the regularizer zeroed or doubled, the loss
+  unchanged.
 
 A step that returns its state unchanged reads 1 on ``change_gap`` by
 construction and needs no run.  Each reading is one JSON line on standard
@@ -27,43 +28,9 @@ import argparse
 import json
 import sys
 from pathlib import Path
-from types import SimpleNamespace
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-
-
-def half_batch(prog, n: int):
-    """The program's step with the second half of every batch left out."""
-    return SimpleNamespace(make_state=prog.make_state,
-                           step=lambda s, b: prog.step(s, {k: v[: n // 2] for k, v in b.items()}))
-
-
-def reg_grad(prog, scale: float):
-    """The program's step with the gradient through the regularizer times
-    ``scale``: the loss ``make_ssl_train_step`` differentiates becomes
-    ``L + lam (scale - 1) (R - stop_gradient(R))``, equal to ``L`` in value.
-    The step's loss is looked up in ``repro.train.ssl`` when it is traced, so
-    it is swapped for the trace alone."""
-    import jax
-
-    import repro.train.ssl as ssl
-
-    def faulty(z1, z2, cfg, perm_key=None):
-        loss, metrics = real(z1, z2, cfg, perm_key=perm_key)
-        r = metrics[f"{cfg.style}_reg"]
-        return loss + cfg.lam * (scale - 1.0) * (r - jax.lax.stop_gradient(r)), metrics
-
-    real = ssl.ssl_loss
-
-    def step_fn(state, batch):
-        ssl.ssl_loss = faulty
-        try:
-            return prog.step_fn(state, batch)
-        finally:
-            ssl.ssl_loss = real
-
-    return SimpleNamespace(make_state=prog.make_state, step=jax.jit(step_fn))
 
 
 def main(argv=None) -> int:
@@ -72,13 +39,13 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--control-seeds", type=int, nargs="*", default=[])
     ap.add_argument("--fault-seeds", type=int, nargs="*", default=[],
-                    help="seeds for each fault: half_batch, reg_grad_zero, reg_grad_double")
+                    help="seeds for each of the program's faults")
     args = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
 
-    from bench import cells, correct, harness, program
+    from bench import cells, correct, harness
     from repro.launch.compile_cache import enable_compile_cache
 
     if jax.devices()[0].platform != "tpu":
@@ -87,10 +54,10 @@ def main(argv=None) -> int:
     enable_compile_cache()
     cell = cells.load_cell(args.workload)
     n = int(cell.traffic["batch"])
+    program = cells.program(cell)
     prog = program.build(cell.config, n)
-    kinds = {"sound": (args.seeds, prog), "half_batch": (args.fault_seeds, half_batch(prog, n)),
-             "reg_grad_zero": (args.fault_seeds, reg_grad(prog, 0.0)),
-             "reg_grad_double": (args.fault_seeds, reg_grad(prog, 2.0)),
+    kinds = {"sound": (args.seeds, prog),
+             **{name: (args.fault_seeds, p) for name, p in program.faults(prog, n).items()},
              "control": (args.control_seeds, prog)}
     summary: dict = {}
     for kind, (seeds, p) in kinds.items():

@@ -5,8 +5,8 @@ Four numbers, each with a limit of its own (``bench/limits/<cell>.json``):
 
 - ``loss_gap``: the largest ``|L - L_ref| / |L_ref|`` over the first steps;
 - ``grad_gap``: the worst leaf's ``| |g| - |g_ref| |`` over ``max(|g_ref|, median
-  leaf |g_ref|)``, where g is the first gradient as LARS keeps it, its momentum
-  after one step;
+  leaf |g_ref|)``, where g is the first gradient as the optimizer keeps it
+  after one step (the program's ``first_gradient``; LARS's momentum);
 - ``change_gap``: the same measure on the parameters' change over the first
   steps, ``p_3 - p_0``;
 - ``direction_gap``: the worst leaf's ``1 - cos`` between the program's change
@@ -30,6 +30,19 @@ import numpy as np
 
 ZERO_GRAD = 1e-3
 NUMBERS = ("loss_gap", "grad_gap", "change_gap", "direction_gap")
+
+
+@jax.jit
+def leaf_norms(tree):
+    """Per-leaf float32 L2 norms, in ``jax.tree.leaves`` order."""
+    return [jnp.linalg.norm(x.astype(jnp.float32)) for x in jax.tree.leaves(tree)]
+
+
+@jax.jit
+def change(new, old):
+    """Per-leaf float32 ``new - old``, in ``jax.tree.leaves`` order."""
+    return [a.astype(jnp.float32) - b.astype(jnp.float32)
+            for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old))]
 
 
 @jax.jit

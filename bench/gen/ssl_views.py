@@ -12,7 +12,9 @@ that the benchmark owns its traffic:
 
 ``host_batch`` renders one batch in NumPy on the host, as the program's own
 ``ssl_batch`` does; ``device_pool`` renders a pool of batches on the device in
-one jitted call.  Both are pure functions of (seed, step).  The device pool
+one jitted call.  Both are pure functions of (seed, step), and both give a
+batch in the form the ``ssl_mlp`` program takes: ``{"view1", "view2"}``, each
+(batch, the configuration's ``input_dim``) float32.  The device pool
 draws from the "rbg" generator (the chip's own bit generator): the TPU
 compiler takes minutes over threefry draws of these sizes, seconds over rbg.
 """
@@ -31,10 +33,10 @@ def _params(traffic: dict) -> tuple[int, float, float, float]:
             float(traffic["mask_prob"]), float(traffic["noise"]))
 
 
-def host_batch(traffic: dict, input_dim: int, seed: int, step: int) -> tuple[np.ndarray, np.ndarray]:
-    """One (batch, input_dim) float32 pair of views, rendered in NumPy."""
+def host_batch(traffic: dict, cfg: dict, seed: int, step: int) -> dict:
+    """One batch of two (batch, input_dim) float32 views, rendered in NumPy."""
     latent, jitter, mask_prob, noise = _params(traffic)
-    n = int(traffic["batch"])
+    n, input_dim = int(traffic["batch"]), int(cfg["input_dim"])
     w = np.random.default_rng([seed, 0]).normal(size=(latent, input_dim)).astype(np.float32)
     w /= np.sqrt(latent)
     rng = np.random.default_rng([seed, 1, step])
@@ -46,7 +48,7 @@ def host_batch(traffic: dict, input_dim: int, seed: int, step: int) -> tuple[np.
         keep = rng.random(size=base.shape) > mask_prob
         v = (base * scale + shift) * keep.astype(np.float32)
         views.append(v + noise * rng.normal(size=base.shape).astype(np.float32))
-    return views[0], views[1]
+    return {"view1": views[0], "view2": views[1]}
 
 
 @partial(jax.jit, static_argnames=("n", "input_dim", "pool", "latent", "jitter", "mask_prob", "noise"))
@@ -72,8 +74,8 @@ def _pool(key, *, n, input_dim, pool, latent, jitter, mask_prob, noise):
     return [{"view1": v1[i], "view2": v2[i]} for i in range(pool)]
 
 
-def device_pool(traffic: dict, input_dim: int, key) -> list[dict]:
+def device_pool(traffic: dict, cfg: dict, key) -> list[dict]:
     """``traffic["pool"]`` batches made on the device; step s uses batch s mod pool."""
     latent, jitter, mask_prob, noise = _params(traffic)
-    return _pool(key, n=int(traffic["batch"]), input_dim=input_dim, pool=int(traffic["pool"]),
+    return _pool(key, n=int(traffic["batch"]), input_dim=int(cfg["input_dim"]), pool=int(traffic["pool"]),
                  latent=latent, jitter=jitter, mask_prob=mask_prob, noise=noise)

@@ -1,13 +1,17 @@
 """One run of one cell: set-up, the first steps that ``correct`` compares, the
 measured window, the reference, and the result line's contents.
 
-Set-up builds the program's jitted step and its state from the seed, makes the
-traffic, and drives the first ``FIRST_STEPS`` steps through ``run_training``
-with the window's own step and batch calls (which compiles the step).  The
-same state then runs on in the window, in chunks of the traffic's
-``log_every`` steps, until ``seconds`` have passed; ``block_until_ready`` on
-the state ends it.  Once the window is closed and the peak memory read, the
-program's state is dropped and the plain reference replays the first steps.
+The harness reaches the model only through the cell's program module
+(``bench/programs/<program>.py``; ``bench/programs/__init__.py`` says what one
+provides), and the traffic's generator gives batches in the form that program
+takes.  Set-up builds the program's jitted step and its state from the seed,
+makes the traffic, and drives the first ``FIRST_STEPS`` steps through
+``run_training`` with the window's own step and batch calls (which compiles
+the step).  The same state then runs on in the window, in chunks of the
+traffic's ``log_every`` steps, until ``seconds`` have passed;
+``block_until_ready`` on the state ends it.  Once the window is closed and
+the peak memory read, the program's state is dropped and the plain reference
+replays the first steps.
 """
 
 from __future__ import annotations
@@ -87,48 +91,46 @@ class Reading:
 
 
 def _traffic(cell: cells.Cell, seed: int, key):
-    """``batch_fn(step)`` of the cell's feed."""
+    """``batch_fn(step)`` of the cell's feed; a host-rendered batch is copied to
+    the device inside the call."""
+    import jax
     import jax.numpy as jnp
 
-    gen = cells.load_module("gen", cell.traffic["generator"])
-    input_dim = int(cell.config["input_dim"])
+    gen = cells.load_module("gen", cell.traffic["generator"], cell.root)
     if cell.traffic["feed"] == "device":
-        pool = gen.device_pool(cell.traffic, input_dim, key)
+        pool = gen.device_pool(cell.traffic, cell.config, key)
         return lambda step: pool[step % len(pool)]
     if cell.traffic["feed"] == "host":
-        def batch_fn(step):
-            v1, v2 = gen.host_batch(cell.traffic, input_dim, seed, step)
-            return {"view1": jnp.asarray(v1), "view2": jnp.asarray(v2)}
-        return batch_fn
+        return lambda step: jax.tree.map(jnp.asarray, gen.host_batch(cell.traffic, cell.config, seed, step))
     raise ValueError(f"unknown feed {cell.traffic['feed']!r}")
 
 
 class Run:
     """A cell's program, state and traffic for one seed, with the calls the
-    window makes: ``train(total, log_every)`` advances ``state`` through
-    ``run_training`` with the spans' step and batch calls."""
+    window makes: ``train(total, log_every)`` advances ``state`` through the
+    program module's ``run_training`` with the spans' step and batch calls.
+    ``prog`` is what the module's ``build`` returns."""
 
     def __init__(self, cell: cells.Cell, seed: int, prog, spans: Spans):
         import jax
 
         self.cell, self.seed, self.prog, self.spans = cell, seed % 2**64, prog, spans
+        self.module = cells.program(cell)
         key = seed_key(seed)
-        self.k_w, self.k_perm, k_data = (jax.random.fold_in(key, i) for i in range(3))
-        self.state = prog.make_state(self.k_w, self.k_perm)
+        self.k_w, self.k_aux, k_data = (jax.random.fold_in(key, i) for i in range(3))
+        self.state = prog.make_state(self.k_w, self.k_aux)
         self.batch_fn = _traffic(cell, self.seed, k_data)
         self.call_step = spans.wrap("dispatch", prog.step)
         self.call_batch = spans.wrap("batch", self.batch_fn)
-        self.loss_key = f"{cell.config['style']}_loss"
+        self.loss_key = prog.loss_key
         self.logged: list = []
 
     def train(self, total: int, log_every: int) -> None:
-        from bench import program
-
         def log_fn(_step, m):
             self.logged.append(m[self.loss_key])
 
-        cfg_loop = program.LoopConfig(total_steps=total, log_interval=log_every)
-        self.state = program.run_training(self.state, self.call_step, self.call_batch, cfg_loop, log_fn=log_fn)
+        cfg_loop = self.module.LoopConfig(total_steps=total, log_interval=log_every)
+        self.state = self.module.run_training(self.state, self.call_step, self.call_batch, cfg_loop, log_fn=log_fn)
 
     def first_steps(self) -> dict:
         """Drive the first ``FIRST_STEPS`` steps (step 0 compiles); return the
@@ -136,16 +138,14 @@ class Run:
         synced, so ``step_peak`` is the memory training needs without the
         window's run-ahead: the state held by ``run_training``'s caller, the
         state a step takes and the one it returns, temporaries and traffic."""
-        from bench import program
-
         self.train(1, 1)
-        grad = [float(x) for x in program.leaf_norms(self.state.opt_state["mu"])]
+        grad = [float(x) for x in correct.leaf_norms(self.prog.first_gradient(self.state))]
         self.train(FIRST_STEPS, 1)
         self.step_peak = peak_bytes()
-        p0 = self.prog.make_state(self.k_w, self.k_perm).params  # the same executable: the same bits
-        delta = program.change(self.state.params, p0)
+        p0 = self.prog.make_state(self.k_w, self.k_aux).params  # the same executable: the same bits
+        delta = correct.change(self.state.params, p0)
         del p0
-        change = [float(x) for x in program.leaf_norms(delta)]
+        change = [float(x) for x in correct.leaf_norms(delta)]
         read = {"loss": list(self.logged), "grad": grad, "change": change, "delta": delta}
         self.logged.clear()
         self.spans.reset()
@@ -154,20 +154,18 @@ class Run:
     def reference(self, **kw) -> dict:
         """The plain reference's readings over the same first batches."""
         batches = [self.batch_fn(s) for s in range(FIRST_STEPS)]
-        ref = cells.load_module("refs", self.cell.config["reference"])
-        return ref.readings(self.cell.config, batches, self.k_w, self.k_perm, **kw)
+        ref = cells.load_module("refs", self.cell.config["reference"], self.cell.root)
+        return ref.readings(self.cell.config, batches, self.k_w, self.k_aux, **kw)
 
 
 def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, t0: float) -> dict:
     """One run; ``t0`` is the process's start on ``time.perf_counter``."""
     import jax
 
-    from bench import program
-
     n, chunk = int(cell.traffic["batch"]), int(cell.traffic["log_every"])
     spans = Spans(annotate=trace)
     marks = [time.perf_counter()]  # set-up's phases, for the log
-    run = Run(cell, seed, program.build(cell.config, n), spans)
+    run = Run(cell, seed, cells.program(cell).build(cell.config, n), spans)
     marks.append(time.perf_counter())
     prog_read = run.first_steps()
     marks.append(time.perf_counter())
@@ -218,7 +216,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, t0: float
         reading = Reading(cell, dev.device_kind, steps, window_s, spans, tr, run.step_peak)
         metrics = {}
         for m in cell.per_layer:
-            value = cells.load_module("metrics", m["name"]).read(reading)
+            value = cells.load_module("metrics", m["name"], cell.root).read(reading)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:

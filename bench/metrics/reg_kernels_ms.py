@@ -4,9 +4,9 @@ The kernels are the custom calls that ``jax.jit(r_sum_kernel)`` puts into the
 step (``repro.kernels.grouped_sumvec.ops``).  The trace names an operation by
 its HLO instruction, and Mosaic's kernels carry no name of their own there:
 these are ``jvp_jit_r_sum_kernel__.<i>`` forward and
-``transpose_jvp_jit_r_sum_kernel___.<i>`` backward, ten a step at d=8192
-(three ``pmatmul`` and two ``freq_outer`` calls, each with its transpose).  A step without them reads
-nothing.
+``transpose_jvp_jit_r_sum_kernel___.<i>`` backward: two calls a step,
+``spectral_gram`` (the forward: both views' block DFTs and the cross-spectra)
+and ``spectral_gram_vjp`` (its backward).  A step without them reads nothing.
 """
 
 from bench import trace

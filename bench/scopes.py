@@ -17,10 +17,11 @@ instruction, so the compiled step's text maps the trace's operations to parts:
   (``encoder.bwd``), otherwise its forward (``encoder.fwd``).
 
 The step is compiled again from the cell's configuration and abstract shapes
-(``bench/program.py``), after the window and past the persistent compile
-cache: the same module gives the same instructions, and the metadata is this
-program's even where the window ran an executable that the cache kept from a
-version without the scopes (the cache's key leaves metadata out).  A program
+(the cell's program module: ``build`` and ``batch_spec``), after the window
+and past the persistent compile cache: the same module gives the same
+instructions, and the metadata is this program's even where the window ran an
+executable that the cache kept from a version without the scopes (the cache's
+key leaves metadata out).  A program
 without the scopes maps nothing, and every part then reads nothing.
 """
 
@@ -76,15 +77,14 @@ def step_hlo(cell) -> str:
     the first device with the shapes the window's steps take: the state as a
     step returns it and a batch of the traffic's size."""
     import jax
-    import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
 
-    from bench import program
+    from bench import cells
 
-    n, width = int(cell.traffic["batch"]), int(cell.config["input_dim"])
-    prog = program.build(cell.config, n)
+    program = cells.program(cell)
+    prog = program.build(cell.config, int(cell.traffic["batch"]))
     key = jax.random.PRNGKey(0)
-    batch = {v: jax.ShapeDtypeStruct((n, width), jnp.float32) for v in ("view1", "view2")}
+    batch = program.batch_spec(cell.config, cell.traffic)
     state = jax.eval_shape(prog.step_fn, jax.eval_shape(prog.make_state, key, key), batch)[0]
     on_device = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_device,

@@ -7,14 +7,14 @@ size."""
 import jax.numpy as jnp
 import pytest
 
-from bench import cells, correct, harness, program
+from bench import cells, correct, harness
 from bench.tests.tiny import cell_names, tiny_cell
 
 
 @pytest.mark.parametrize("name", cell_names())
 def test_bfloat16_reference_is_not_correct(name):
     cell = tiny_cell(name)
-    run = harness.Run(cell, 2**31 + 5, program.build(cell.config, int(cell.traffic["batch"])),
+    run = harness.Run(cell, 2**31 + 5, cells.program(cell).build(cell.config, int(cell.traffic["batch"])),
                       harness.Spans(annotate=False))
     ref = run.reference()
     control = run.reference(dtype=jnp.bfloat16, precision="default")

@@ -5,25 +5,24 @@ Each run skips the harness's look for a chip and drives the rest of a run with
 the cell's own limits.  The faults a one-chip training cell can have: a step
 that returns its state unchanged, and half of the batch left out with the mean
 taken over the rest; and, for the regularizer's backward pass, the gradient
-through the regularizer zeroed with the loss unchanged.
+through the regularizer zeroed with the loss unchanged.  All but the first are
+the program module's own (``faults``), the ones that are read on the chip.
 """
 
 import pytest
 
-from bench import calibrate, program
+from bench import cells
 from bench.tests.runs import run
 from bench.tests.tiny import cell_names, tiny_cell
 
 
-def _broken(fault, n):
-    real = program.build
+def _broken(module, fault, n):
+    real = module.build
 
     def build(cfg, batch):
         prog = real(cfg, batch)
-        if fault == "half_batch":
-            return calibrate.half_batch(prog, n)  # the faults read on the chip
-        if fault == "reg_grad_zero":
-            return calibrate.reg_grad(prog, 0.0)
+        if fault != "state_unchanged":
+            return module.faults(prog, n)[fault]
         step = prog.step
         prog.step = lambda s, b: (s, step(s, b)[1])
         return prog
@@ -35,7 +34,8 @@ def _broken(fault, n):
 @pytest.mark.parametrize("name", cell_names())
 def test_broken_step_is_not_correct(name, fault, monkeypatch):
     cell = tiny_cell(name)
-    monkeypatch.setattr(program, "build", _broken(fault, int(cell.traffic["batch"])))
+    module = cells.program(cell)
+    monkeypatch.setattr(module, "build", _broken(module, fault, int(cell.traffic["batch"])))
     r = run(cell)
     assert not r["correct"], r["checks"]
 

@@ -19,6 +19,13 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in M["workloads"]]
+# what a program module provides (bench/programs/__init__.py's docstring)
+PROGRAM_API = ("LoopConfig", "run_training", "build", "batch_spec", "step_flops", "regularizer_flops", "faults",
+               "tiny")
+# the harness's files, which reach the model only through a program module
+HARNESS = [ROOT / "bench" / f"{m}.py" for m in ("cells", "harness", "scopes", "calibrate", "correct", "trace", "run")]
+HARNESS += sorted((ROOT / "bench" / "metrics").glob("*.py"))
+MODEL_WORDS = ("input_dim", "backbone_widths", "projector_width", "view1", '"style"', '"mu"')
 
 
 def test_top_level_keys():
@@ -71,12 +78,32 @@ def test_cell_files_resolve_by_name(cell):
     assert c.config["name"] == next(w["config"] for w in M["workloads"] if w["name"] == cell)
     assert c.traffic["name"] == next(w["traffic"] for w in M["workloads"] if w["name"] == cell)
     assert set(c.limits) == set(correct.NUMBERS)
+    program = cells.program(c)
+    assert all(callable(getattr(program, f)) for f in PROGRAM_API)
     cells.load_module("refs", c.config["reference"])
     cells.load_module("gen", c.traffic["generator"])
     for m in c.per_layer:
         assert callable(cells.load_module("metrics", m["name"]).read)
     e2e = {e["name"] for e in c.end_to_end}
     assert "setup_s" in e2e and len(e2e) >= 2 and c.per_layer
+
+
+def test_a_configuration_must_name_its_program(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    conf = M["configs"][0]
+    data = json.loads((ROOT / conf["file"]).read_text())
+    del data["program"]
+    (tmp_path / conf["file"]).parent.mkdir(parents=True)
+    (tmp_path / conf["file"]).write_text(json.dumps(data))
+    cell = next(w["name"] for w in M["workloads"] if w["config"] == conf["name"])
+    with pytest.raises(KeyError, match='"program"'):
+        cells.load_cell(cell, tmp_path)
+
+
+@pytest.mark.parametrize("path", HARNESS, ids=lambda p: p.relative_to(ROOT).as_posix())
+def test_harness_names_no_model(path):
+    text = path.read_text()
+    assert not [w for w in MODEL_WORDS if w in text]
 
 
 def test_config_files_are_their_own():
@@ -150,7 +177,8 @@ def test_command_refuses_a_checkout_without_the_program(tmp_path):
 def test_import_describes_no_topology():
     code = (
         "import sys; sys.path[:0] = [sys.argv[1], sys.argv[1] + '/src']\n"
-        "from bench import cells, harness, trace, flops, peaks, correct, program, calibrate\n"
+        "from bench import cells, harness, trace, peaks, correct, calibrate\n"
+        "import bench.programs.ssl_mlp\n"
         "m = cells.manifest()\n"
         "[cells.load_module('metrics', p['name']) for p in m['per_layer']]\n"
         "[cells.load_module('refs', 'bt_mlp'), cells.load_module('gen', 'ssl_views')]\n"

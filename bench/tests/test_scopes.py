@@ -128,13 +128,13 @@ def test_the_abstract_compile_is_the_steps_own(cell):
     run on real arrays compiles, as the window runs it (after a first step)."""
     import jax
 
-    from bench import program
-
     c = tiny_cell(cell)
-    n, width = int(c.traffic["batch"]), int(c.config["input_dim"])
-    prog = program.build(c.config, n)
+    program = cells.program(c)
+    prog = program.build(c.config, int(c.traffic["batch"]))
     key = jax.random.PRNGKey(3)
-    batch = {v: jax.random.normal(jax.random.fold_in(key, i), (n, width)) for i, v in enumerate(("view1", "view2"))}
+    spec = program.batch_spec(c.config, c.traffic)
+    batch = {v: jax.random.normal(jax.random.fold_in(key, i), s.shape, s.dtype)
+             for i, (v, s) in enumerate(spec.items())}
     state, _ = prog.step(prog.make_state(key, key), batch)
     real = scopes.op_names(prog.step.lower(state, batch).compile().as_text())
     abstract = scopes.op_names(scopes.step_hlo(c))

@@ -1,5 +1,6 @@
 """A cell of ``BENCHMARK.json`` cut to a size that the CPU runs in seconds:
-the same configuration, traffic and limits, with small widths and batch."""
+the same configuration, traffic and limits, with the small widths and batch
+that the cell's program module gives (its ``tiny``)."""
 
 from __future__ import annotations
 
@@ -10,12 +11,7 @@ from bench import cells
 
 def tiny_cell(name: str) -> cells.Cell:
     cell = cells.load_cell(name)
-    cfg = dict(cell.config, input_dim=32, backbone_widths=[32], projector_width=512)
-    if cfg["reg"] == "sum":
-        cfg["block_size"] = 32
-    traffic = dict(cell.traffic, batch=16, log_every=5)
-    if "pool" in traffic:
-        traffic["pool"] = 4
+    cfg, traffic = cells.program(cell).tiny(cell.config, cell.traffic)
     return dataclasses.replace(cell, config=cfg, traffic=traffic)
 
 
